@@ -27,8 +27,8 @@ from repro.core import (CPU_PAPER_POWER, TPU_V5E_POWER, BlockInfo, plan_dvfs,
                         plan_dvo, simulate, variety_stats)
 from repro.data import BlockDataset
 
-__all__ = ["motivation_table", "run_app_comparison", "fig6_10", "fig11_12",
-           "fig13"]
+__all__ = ["motivation_table", "run_app_comparison", "compare_on_measured",
+           "measure_blocks", "fig6_10", "fig11_12", "fig13"]
 
 SLACK = {"tight": 1.08, "firm": 1.20}
 
@@ -84,15 +84,25 @@ def _measure_app(app_name: str, ds: BlockDataset, repeats: int = 3,
 
 def _measure_app_uncached(app_name: str, ds: BlockDataset, repeats: int = 3,
                           sample_fraction: float = 0.05, seed: int = 0):
-    app = ALL_APPS[app_name]()
     with_tokens = _APP_BLOCKS[app_name]["with_tokens"]
+    return measure_blocks(app_name,
+                          (ds.block(i, with_tokens=with_tokens)
+                           for i in range(ds.n_blocks)),
+                          repeats=repeats, sample_fraction=sample_fraction,
+                          seed=seed)
+
+
+def measure_blocks(app_name: str, blocks, repeats: int = 3,
+                   sample_fraction: float = 0.05, seed: int = 0):
+    """(times, t_subs): measured seconds of ``app_name`` over each host block
+    dict, and over a ``sample_fraction`` row slice of it (at least 64 rows)."""
+    app = ALL_APPS[app_name]()
     keys = _APP_KEYS[app_name]
     rng = np.random.default_rng(seed)
     times, t_subs = [], []
-    n = ds.records_per_block
-    k = max(64, int(round(sample_fraction * n)))
-    for i in range(ds.n_blocks):
-        b = ds.block(i, with_tokens=with_tokens)
+    for b in blocks:
+        n = len(b[keys[0]])
+        k = max(64, int(round(sample_fraction * n)))
         blk = {kk: jnp.asarray(b[kk]) for kk in keys}
         times.append(measure_block_seconds(app, blk, repeats=repeats))
         rows = np.sort(rng.choice(n, size=k, replace=False))
@@ -118,11 +128,20 @@ def run_app_comparison(app_name: str, *, z: float = 1.0, slack: float = 1.20,
     ds = _dataset(app_name, z=z, seed=seed)
     times, t_sub = _measure_app(app_name, ds, sample_fraction=sample_fraction,
                                 seed=seed)
+    return {"app": app_name, "z": z,
+            **compare_on_measured(times, t_sub, slack=slack, planner=planner,
+                                  power=power)}
 
+
+def compare_on_measured(times, t_sub, *, slack: float = 1.20,
+                        planner: str = "paper", power=CPU_PAPER_POWER) -> dict:
+    """Plan from the sampled times, simulate on the full ones, vs DVO."""
+    times, t_sub = np.asarray(times), np.asarray(t_sub)
+    n_blocks = len(times)
     # pre-processing/estimator box (paper Fig. 3): affine calibration
     # t_full ≈ a + b·t_sample on 3 fully-measured blocks corrects the fixed
     # overhead (vocab-sized outputs, dispatch) that does not scale with rows
-    calib = [0, ds.n_blocks // 2, ds.n_blocks - 1]
+    calib = [0, n_blocks // 2, n_blocks - 1]
     x = np.stack([np.ones(len(calib)), t_sub[calib]], axis=1)
     coef, *_ = np.linalg.lstsq(x, times[calib], rcond=None)
     est = np.maximum(coef[0] + coef[1] * t_sub, 1e-9)
@@ -136,7 +155,7 @@ def run_app_comparison(app_name: str, *, z: float = 1.0, slack: float = 1.20,
     dvo = simulate(plan_dvo(true_blocks, deadline, power=power), true_blocks,
                    power=power)
     return {
-        "app": app_name, "z": z, "slack": slack, "planner": planner,
+        "slack": slack, "planner": planner,
         "deadline_s": deadline,
         "dvo_time_s": dvo.total_time_s, "dvo_energy_j": dvo.total_energy_j,
         "dvfs_time_s": rep.total_time_s, "dvfs_energy_j": rep.total_energy_j,

@@ -254,9 +254,9 @@ def main():
         "`PYTHONPATH=src python -m benchmarks.run`, "
         "`PYTHONPATH=src python -m repro.launch.dryrun --all --both-meshes "
         "[--opt]`.  Hardware model: TPU v5e-class (197 TFLOP/s bf16, "
-        "819 GB/s HBM, 16 GB, ~50 GB/s/link ICI); container is CPU-only so "
-        "kernels are validated in interpret mode and DVFS actuation is "
-        "simulated (DESIGN.md §9).",
+        "819 GB/s HBM, 16 GB, ~50 GB/s/link ICI); these numbers come from "
+        "CPU runs, where the kernels run in the Pallas interpreter, and "
+        "DVFS actuation is simulated (DESIGN.md §9).",
         "",
         paper_section("results/bench.json"),
         "",

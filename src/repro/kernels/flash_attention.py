@@ -78,7 +78,7 @@ def flash_attention_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, swa_window=None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (B, Hq, S, D), k/v: (B, Hkv, S, D) -> (B, Hq, S, D).
 
     Hq must be a multiple of Hkv (GQA); the kv index_map routes each q head to
@@ -117,4 +117,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, swa_window=None,
             pltpu.VMEM((block_q, d), jnp.float32), # accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the container is CPU-only: the kernel
-body executes in Python for validation); on a TPU backend pass interpret=False
-to compile the real Mosaic kernels.
+``interpret=None`` resolves through ``default_interpret``: on a TPU the
+kernels compile with Mosaic; on the CPU (the test suite, with
+``JAX_PLATFORMS=cpu``) the kernel bodies run in the Pallas interpreter;
+any other backend is an error rather than a silent fallback.
 """
 from __future__ import annotations
 
@@ -21,7 +22,15 @@ __all__ = ["flash_attention", "ssd_scan", "block_stats",
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas execution mode for the default backend: False (Mosaic) on a
+    TPU, True (interpreter) on the CPU; raises on anything else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas execution mode for backend {backend!r}: "
+                       "kernels compile on 'tpu' and interpret on 'cpu'")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "swa_window", "block_q",

@@ -24,8 +24,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ssd_scan_kernel", "ssd_scan_pallas"]
 
 
-def ssd_scan_kernel(x_ref, dt_ref, dta_ref, b_ref, c_ref, y_ref, h_scr, *,
-                    chunk: int):
+def ssd_scan_kernel(x_ref, dt_ref, dta_ref, dta_row_ref, b_ref, c_ref, y_ref,
+                    h_scr, *, chunk: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -35,29 +35,38 @@ def ssd_scan_kernel(x_ref, dt_ref, dta_ref, b_ref, c_ref, y_ref, h_scr, *,
     x = x_ref[0].astype(jnp.float32)          # (q, P)
     dt = dt_ref[0].astype(jnp.float32)        # (q, 1)
     dta = dta_ref[0].astype(jnp.float32)      # (q, 1)
+    dta_row = dta_row_ref[0].astype(jnp.float32)  # (1, q)
     b = b_ref[0].astype(jnp.float32)          # (q, N)
     c = c_ref[0].astype(jnp.float32)          # (q, N)
 
-    seg = jnp.cumsum(dta[:, 0])               # (q,)
-    li = seg[:, None] - seg[None, :]          # (q, q)
+    # inclusive prefix sums of dt·A as masked (q, q) reductions, in both
+    # layouts: seg[i] = Σ_{k<=i} dta[k] down the column, and across the row
     iot = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jot = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(iot >= jot, jnp.exp(li), 0.0)
+    lower = iot >= jot
+    seg = jnp.sum(jnp.where(lower, dta_row, 0.0), axis=1,
+                  keepdims=True)                                   # (q, 1)
+    seg_row = jnp.sum(jnp.where(iot <= jot, dta, 0.0), axis=0,
+                      keepdims=True)                               # (1, q)
+    seg_last = jnp.sum(dta, axis=0, keepdims=True)                 # (1, 1)
+    decay = jnp.where(lower, jnp.exp(seg - seg_row), 0.0)          # (q, q)
 
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))   # (q, q)
     xw = x * dt                               # dt ⊙ X  (q, P)
     y_intra = (scores * decay) @ xw
-    y_inter = jnp.exp(seg)[:, None] * (c @ h_scr[:].T)             # (q, P)...
+    y_inter = jnp.exp(seg) * jax.lax.dot_general(
+        c, h_scr[:], (((1,), (1,)), ((), ())))                     # (q, P)
 
-    tail = jnp.exp(seg[-1] - seg)             # (q,)
-    state_upd = (b * (tail * dt[:, 0])[:, None]).T @ x             # (N, P)
-    h_scr[:] = h_scr[:] * jnp.exp(seg[-1]) + state_upd.T           # (P, N)
+    tail = jnp.exp(seg_last - seg)            # (q, 1)
+    state_upd = jax.lax.dot_general(
+        x, b * (tail * dt), (((0,), (0,)), ((), ())))              # (P, N)
+    h_scr[:] = h_scr[:] * jnp.exp(seg_last) + state_upd
 
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
 
 def ssd_scan_pallas(x, dt, a_log, b_mat, c_mat, *, chunk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """x: (BH, S, P), dt: (BH, S), b/c: (BH, S, N) -> (y (BH, S, P), h (BH,P,N)).
 
     Wrapper flattens (batch, heads) and repeats grouped B/C outside (ops.py).
@@ -78,6 +87,7 @@ def ssd_scan_pallas(x, dt, a_log, b_mat, c_mat, *, chunk: int = 128,
             pl.BlockSpec((1, chunk, p), lambda bi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, 1), lambda bi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, 1), lambda bi, ci: (bi, ci, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda bi, ci: (bi, 0, ci)),
             pl.BlockSpec((1, chunk, n), lambda bi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, ci: (bi, ci, 0)),
         ],
@@ -85,5 +95,6 @@ def ssd_scan_pallas(x, dt, a_log, b_mat, c_mat, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((bh, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt[..., None], dta[..., None], b_mat, c_mat)
+        name="ssd_scan",
+    )(x, dt[..., None], dta[..., None], dta[:, None, :], b_mat, c_mat)
     return y

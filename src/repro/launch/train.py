@@ -1,8 +1,8 @@
 """Production training driver: --arch <id> over the block data pipeline with
 DV-DVFS, checkpoints, restart and straggler detection.
 
-On accelerator hosts this runs the full config under the ambient device set;
-on this CPU container use --preset smoke (reduced same-family config).
+--preset full runs the published config on the default device (a TPU);
+--preset smoke is the reduced same-family config, for the CPU.
 
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --preset smoke \
       --steps 30 --ckpt-dir /tmp/ck
@@ -13,6 +13,7 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_arch, smoke_config
 from repro.data import BlockDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer
 
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.preset == "smoke" \
         else get_arch(args.arch)
     print(f"[train] arch={cfg.name} preset={args.preset} "

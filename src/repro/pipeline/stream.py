@@ -23,7 +23,7 @@ from repro.core.scheduler import plan_dvfs_arrays
 from repro.core.soa import BlockArrays, EstimateArrays, PlanArrays
 
 __all__ = ["PipelineConfig", "stream_estimates", "stream_estimates_tokens",
-           "token_chunk_estimates", "plan_estimates", "stream_plan",
+           "sample_token_rows", "token_chunk_estimates", "plan_estimates", "stream_plan",
            "stream_run"]
 
 # default linear record-cost model over the kernel's per-row features:
@@ -116,6 +116,33 @@ def stream_estimates(source, config: PipelineConfig = PipelineConfig()
     return EstimateArrays.concat(parts)
 
 
+def sample_token_rows(tokens: np.ndarray, *, start_index: int,
+                      config: PipelineConfig = PipelineConfig()) -> tuple:
+    """The rows ``token_chunk_estimates`` reads from one (B, R, L) chunk.
+
+    Returns ``(sampled (B, kmax, L), k (B,))``: each block's ``k`` rows
+    picked by the sampler's stateless hash keyed by global block index, in
+    hash order (every block samples the same ``k`` here).
+    """
+    tokens = np.asarray(tokens)
+    b, r, length = tokens.shape
+    index = start_index + np.arange(b, dtype=np.int64)
+    k = np.minimum(r, np.maximum(max(int(config.min_samples), 1),
+                                 int(np.ceil(config.fraction * r))))
+    k = np.full(b, k, dtype=np.int64)
+    kmax = int(k.max()) if b else 0
+    if kmax == 0:
+        return np.zeros((b, 0, length), tokens.dtype), k
+    keys = _hash_uniform(config.seed, index[:, None],
+                         np.arange(r, dtype=np.int64)[None, :],
+                         domain=_DOMAIN_SAMPLER)
+    part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
+    order = np.argsort(np.take_along_axis(keys, part, axis=1), axis=1,
+                       kind="stable")
+    sel = np.take_along_axis(part, order, axis=1)
+    return np.take_along_axis(tokens, sel[:, :, None], axis=1), k
+
+
 def token_chunk_estimates(
     tokens: np.ndarray,
     *,
@@ -150,22 +177,13 @@ def token_chunk_estimates(
         return EstimateArrays(index, total, total - hw, total + hw,
                               np.zeros(b, dtype=np.int64),
                               np.full(b, r, dtype=np.int64))
-    k = np.minimum(r, np.maximum(max(int(config.min_samples), 1),
-                                 int(np.ceil(config.fraction * r))))
-    k = np.full(b, k, dtype=np.int64)
-    kmax = int(k.max()) if b else 0
+    sampled, k = sample_token_rows(tokens, start_index=start_index,
+                                   config=config)
+    kmax = sampled.shape[1]
     if kmax == 0:
         z0 = np.zeros(b)
         return EstimateArrays(index, z0, z0.copy(), z0.copy(), k,
                               np.full(b, r, dtype=np.int64))
-    keys = _hash_uniform(config.seed, index[:, None],
-                         np.arange(r, dtype=np.int64)[None, :],
-                         domain=_DOMAIN_SAMPLER)
-    part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
-    order = np.argsort(np.take_along_axis(keys, part, axis=1), axis=1,
-                       kind="stable")
-    sel = np.take_along_axis(part, order, axis=1)
-    sampled = np.take_along_axis(tokens, sel[:, :, None], axis=1)
 
     # block-level sampled features: ONE fused kernel dispatch for the chunk
     stats = np.asarray(ops.block_stats_batched(

@@ -28,7 +28,15 @@ from repro.train.dvfs_controller import (DVFSController, EnergyLedger,
                                          SimulatedActuator)
 from repro.train.straggler import StragglerDetector
 
-__all__ = ["TrainConfig", "make_train_step", "Trainer"]
+__all__ = ["TrainConfig", "make_train_step", "Trainer", "InjectedFailure",
+           "CALIBRATION_STEPS"]
+
+# timed f_max steps the cost model is fitted on (after one warm-up step)
+CALIBRATION_STEPS = 3
+
+
+class InjectedFailure(Exception):
+    """The simulated node failure of ``Trainer.run(inject_failure_at=...)``."""
 
 
 @dataclasses.dataclass
@@ -131,7 +139,7 @@ class Trainer:
         lr_fn = linear_warmup_cosine(tc.lr, tc.warmup, tc.total_steps)
         self._step_fn = jax.jit(make_train_step(
             cfg, self.opt_cfg, num_microbatches=tc.num_microbatches,
-            clip_norm=tc.clip_norm, lr_fn=lr_fn))
+            clip_norm=tc.clip_norm, lr_fn=lr_fn), donate_argnums=(0, 1))
         self.ckpt = CheckpointManager(tc.ckpt_dir, keep=tc.ckpt_keep)
         self.actuator = SimulatedActuator(roofline)
         self.ledger = EnergyLedger(chips=chips)
@@ -148,23 +156,13 @@ class Trainer:
                  "labels": jnp.asarray(packed.labels)}, packed.nonpad_tokens)
 
     # ------------------------------------------------------------ dv-dvfs --
-    def _calibrate_and_plan(self, params, opt_state):
-        """Sample blocks, calibrate the cost model on a few measured steps,
-        plan frequencies for the epoch (paper Fig. 3 pre-processing box)."""
-        n_blocks = self.dataset.n_blocks
-        feats, meas = [], []
-        # measure 3 calibration blocks at f_max
-        for i in range(min(3, n_blocks)):
-            batch, nonpad = self._block_batch(i)
-            t0 = time.perf_counter()
-            p2, o2, _ = self._step_fn(params, opt_state, batch)
-            jax.block_until_ready(p2)
-            meas.append(time.perf_counter() - t0)
-            feats.append({"tokens": float(nonpad), "const": 1.0})
-        cm = CostModel(("tokens", "const")).fit(feats, meas)
-
+    def _plan(self, calib: list):
+        """Fit the cost model on the timed f_max steps, then plan frequencies
+        for the epoch (paper Fig. 3 pre-processing box)."""
+        feats, meas = zip(*calib)
+        cm = CostModel(("tokens", "const")).fit(list(feats), list(meas))
         block_feats = []
-        for i in range(n_blocks):
+        for i in range(self.dataset.n_blocks):
             st = self.dataset.stats(i)
             # sampling sees record-level stats only (paper's <1% overhead)
             block_feats.append({"tokens": float(st.tokens) * self.tc.batch
@@ -183,6 +181,16 @@ class Trainer:
     # ------------------------------------------------------------- run -----
     def run(self, *, resume: bool = True,
             inject_failure_at: int | None = None) -> dict:
+        """Train to ``total_steps``.
+
+        The step donates its params and optimizer state, so the cost model
+        is calibrated on the run's own first steps rather than on repeated
+        calls over one state: the step is compiled before the loop, the
+        first executed step is the warm-up, and the next
+        ``CALIBRATION_STEPS`` (at f_max) are timed before the plan is made.
+        Only an ``InjectedFailure`` is recovered from; any other error
+        (a device fault, an out-of-memory) ends the run.
+        """
         params = T.init_params(self.cfg, jax.random.PRNGKey(self.tc.seed))
         opt_state = adamw_init(params, self.opt_cfg)
         start_step = 0
@@ -193,8 +201,9 @@ class Trainer:
                 tree, start_step = restored
                 params, opt_state = tree["params"], tree["opt"]
 
-        if self.tc.dvfs_enabled and self.controller is None:
-            self._calibrate_and_plan(params, opt_state)
+        step_fn = self._step_fn.lower(
+            params, opt_state, self._block_batch(0)[0]).compile()
+        calib: list = []  # (features, wall) of executed f_max steps
 
         step = start_step
         failed = False
@@ -211,11 +220,10 @@ class Trainer:
                 if inject_failure_at is not None and step == inject_failure_at \
                         and not failed:
                     failed = True
-                    raise RuntimeError("injected node failure")
-                params, opt_state, metrics = self._step_fn(
-                    params, opt_state, batch)
+                    raise InjectedFailure(f"injected node failure at {step}")
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
                 jax.block_until_ready(metrics["loss"])
-            except RuntimeError:
+            except InjectedFailure:
                 # fault tolerance: restore newest valid checkpoint and continue
                 restored = self.ckpt.restore_latest(
                     {"params": params, "opt": opt_state})
@@ -229,6 +237,11 @@ class Trainer:
                     params, opt_state = tree["params"], tree["opt"]
                 continue
             wall = time.perf_counter() - t0
+
+            if self.tc.dvfs_enabled and self.controller is None:
+                calib.append(({"tokens": float(nonpad), "const": 1.0}, wall))
+                if len(calib) == 1 + CALIBRATION_STEPS:
+                    self._plan(calib[1:])  # calib[0] is the warm-up
 
             eff = self.actuator.effective_time(wall)
             self.ledger.record(eff, rel_freq)

@@ -15,6 +15,7 @@ from repro.models import transformer as T
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          clip_by_global_norm, linear_warmup_cosine)
 from repro.train import StragglerDetector, TrainConfig, Trainer
+from repro.train.loop import CALIBRATION_STEPS
 
 
 def _tree(seed=0):
@@ -104,6 +105,31 @@ def test_trainer_failure_recovery_is_bitexact(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("error", [RuntimeError("device fault"),
+                                   jax.errors.JaxRuntimeError(
+                                       "RESOURCE_EXHAUSTED: device fault")],
+                         ids=["runtime_error", "jax_runtime_error"])
+def test_trainer_device_error_ends_run(tmp_path, error):
+    """Only the injected failure is recovered from; a real error from the
+    step (an HBM OOM, a device fault) propagates out of run()."""
+    tr = _mk_trainer(tmp_path)
+
+    class FailingStep:
+        def lower(self, *args):
+            return self
+
+        def compile(self):
+            return self
+
+        def __call__(self, *args):
+            raise error
+
+    tr._step_fn = FailingStep()
+    with pytest.raises(RuntimeError, match="device fault"):
+        tr.run(resume=False)
+    assert tr.history == []
+
+
 def test_trainer_dvfs_saves_energy(tmp_path):
     res = _mk_trainer(tmp_path, dvfs_enabled=True, total_steps=16,
                       deadline_slack=1.3).run(resume=False)
@@ -112,6 +138,33 @@ def test_trainer_dvfs_saves_energy(tmp_path):
     assert res["energy"]["busy_j"] <= res["energy_dvo"]["busy_j"] * 1.001
     freqs = {h["rel_freq"] for h in res["history"]}
     assert any(f < 1.0 for f in freqs)  # it actually down-clocked something
+
+
+def test_trainer_dvfs_calibrates_on_its_own_steps(tmp_path, monkeypatch):
+    """A DVFS run takes its first 1 + CALIBRATION_STEPS steps unplanned at
+    f_max, fits the cost model on all of them but the warm-up step 0, and
+    runs every later step at the planned frequency of its block."""
+    tr = _mk_trainer(tmp_path, dvfs_enabled=True, total_steps=10,
+                     deadline_slack=1.3)
+    fits = []
+    plan = tr._plan
+
+    def spy(calib):
+        fits.append((len(tr.history), list(calib)))
+        return plan(calib)
+
+    monkeypatch.setattr(tr, "_plan", spy)
+    hist = tr.run(resume=False)["history"]
+    n = 1 + CALIBRATION_STEPS
+    assert [h["rel_freq"] for h in hist[:n]] == [1.0] * n
+    (planned_in_step, calib), = fits
+    assert planned_in_step == n - 1
+    assert [wall for _, wall in calib] == [h["wall_s"] for h in hist[1:n]]
+    assert tr.controller is not None and tr.controller.plan is not None
+    blocks = tr.dataset.n_blocks
+    assert [h["rel_freq"] for h in hist[n:]] == [
+        tr.controller.freq_for_block(h["step"] // tr.tc.steps_per_block
+                                     % blocks) for h in hist[n:]]
 
 
 def test_straggler_detector():

@@ -87,3 +87,14 @@ def test_multi_replica_decode_windows():
     # aggregate across replicas still saves energy vs all-f_max
     assert out["energy"]["busy_j"] <= out["energy_dvo"]["busy_j"] * 1.01
     assert out["energy"]["steps"] > out1["energy"]["steps"]
+
+
+def test_launch_serve_smoke_preset(monkeypatch, capsys):
+    """The serving entry point runs its smoke preset's request shape; only
+    batch and token count may be overridden."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: "off")
+    serve.main(["--arch", "olmo-1b", "--batch", "1", "--tokens", "9"])
+    out = capsys.readouterr().out
+    assert "preset=smoke generated=" in out
+    assert int(out.split("generated=")[1].split()[0]) >= 9
