@@ -17,6 +17,7 @@ from typing import Callable, Sequence
 import numpy as np
 
 from repro.core.soa import EstimateArrays
+from repro.obs.tracer import count, span
 
 __all__ = ["BlockEstimate", "sample_block_cost", "sample_blocks",
            "sample_blocks_soa", "required_sample_size"]
@@ -284,35 +285,44 @@ def sample_blocks_soa(
         z0 = np.zeros(b)
         return EstimateArrays(index, z0, z0.copy(), z0.copy(),
                               k, n)
-    slots = np.arange(r, dtype=np.int64)
-    keys = _hash_uniform(seed, index[:, None], slots[None, :],
-                         domain=_DOMAIN_SAMPLER)
+    with span("sample.keys", keys=b * r):
+        slots = np.arange(r, dtype=np.int64)
+        keys = _hash_uniform(seed, index[:, None], slots[None, :],
+                             domain=_DOMAIN_SAMPLER)
     uniform = lengths is None and int(k.min()) == kmax
-    if not uniform:
-        keys = np.where(slots[None, :] < n[:, None], keys, np.inf)
-    # exact without-replacement sample: each block's k smallest keys
-    if kmax < r:
-        part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
-    else:
-        part = np.broadcast_to(slots[None, :], (b, r))
-    if uniform:
-        # every block samples exactly kmax records: the k-smallest SET is all
-        # that matters for mean/variance, so skip the within-row sort+mask
-        sampled = np.take_along_axis(costs, part, axis=1)
-        mean = sampled.mean(axis=1)
-        var = ((sampled - mean[:, None]) ** 2).sum(axis=1) / max(kmax - 1, 1)
-        ksafe = np.float64(kmax)
-    else:
-        order = np.argsort(np.take_along_axis(keys, part, axis=1), axis=1,
-                           kind="stable")
-        sel = np.take_along_axis(part, order, axis=1)
-        sampled = np.take_along_axis(costs, sel, axis=1)
-        m = np.arange(kmax)[None, :] < k[:, None]
-        ksafe = np.maximum(k, 1).astype(np.float64)
-        mean = np.where(m, sampled, 0.0).sum(axis=1) / ksafe
-        resid = np.where(m, sampled - mean[:, None], 0.0)
-        var = (resid ** 2).sum(axis=1) / np.maximum(k - 1, 1)
-    se = np.sqrt(var / ksafe)
-    hw = _z_for_confidence(confidence) * se * n
-    total = mean * n
+    rows = int(k.sum())
+    with span("sample.select", rows=rows):
+        if not uniform:
+            keys = np.where(slots[None, :] < n[:, None], keys, np.inf)
+        # exact without-replacement sample: each block's k smallest keys
+        if kmax < r:
+            part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
+        else:
+            part = np.broadcast_to(slots[None, :], (b, r))
+        if uniform:
+            # every block samples exactly kmax records: the k-smallest SET is
+            # all that matters for mean/variance, so skip the within-row
+            # sort+mask
+            sampled = np.take_along_axis(costs, part, axis=1)
+        else:
+            order = np.argsort(np.take_along_axis(keys, part, axis=1),
+                               axis=1, kind="stable")
+            sel = np.take_along_axis(part, order, axis=1)
+            sampled = np.take_along_axis(costs, sel, axis=1)
+        count(bytes=sampled.nbytes)
+    with span("sample.stats", rows=rows):
+        if uniform:
+            mean = sampled.mean(axis=1)
+            var = ((sampled - mean[:, None]) ** 2).sum(axis=1) \
+                / max(kmax - 1, 1)
+            ksafe = np.float64(kmax)
+        else:
+            m = np.arange(kmax)[None, :] < k[:, None]
+            ksafe = np.maximum(k, 1).astype(np.float64)
+            mean = np.where(m, sampled, 0.0).sum(axis=1) / ksafe
+            resid = np.where(m, sampled - mean[:, None], 0.0)
+            var = (resid ** 2).sum(axis=1) / np.maximum(k - 1, 1)
+        se = np.sqrt(var / ksafe)
+        hw = _z_for_confidence(confidence) * se * n
+        total = mean * n
     return EstimateArrays(index, total, total - hw, total + hw, k, n)

@@ -1,7 +1,15 @@
-"""Fleet observatory: spans, metrics, exporters, attribution, what-ifs.
+"""Fleet observatory: live spans, and views of the simulated runtime.
 
-Seven views of one run, all derived from the same deterministic event
-stream the runtime engines emit (scalar and vector logs are
+:mod:`repro.obs.tracer` records the real host work of the chip path as it
+runs: spans at the layer boundaries of the estimate front
+(``pipeline/stream.py``, ``core/sampling.py``) and of the planner, with
+counts and the backend compilations each span caused.  Each span is also a
+``jax.profiler.TraceAnnotation``, so it shows in a profiler trace of a real
+job beside the device's ops, and ``write_chrome_trace(path,
+spans={"host": tracer.forest()})`` dumps the recorder's ring.
+
+Seven views of a simulated run, all derived from the same deterministic
+event stream the runtime engines emit (scalar and vector logs are
 bitwise-identical, so every artifact here is too):
 
 * :mod:`repro.obs.spans` — per-block / per-job lifecycle span trees
@@ -21,28 +29,38 @@ bitwise-identical, so every artifact here is too):
 * :mod:`repro.obs.watchdog` — SRE-style multi-window SLO burn-rate
   alerting off the streaming metrics, deterministic alert streams.
 """
-from repro.obs.counterfactual import (MECHANISMS, Scenario, ablate,
-                                      delta_ledger, mechanism_columns,
-                                      neutralize, profile_mechanisms)
-from repro.obs.diff import RunDiff, diff_runs
-from repro.obs.explain import explain_energy, explain_miss
-from repro.obs.export import (to_chrome_trace, to_jsonl, to_prometheus,
-                              validate_chrome_trace, validate_prometheus,
-                              write_chrome_trace, write_jsonl)
-from repro.obs.metrics import (StreamingMetrics, format_table, node_rows,
-                               tenant_rows)
-from repro.obs.spans import (Span, build_job_spans, build_spans, flatten,
-                             require_full_log)
-from repro.obs.watchdog import Alert, Rule, Watchdog, standard_rules
 
-__all__ = [
-    "Span", "build_spans", "build_job_spans", "flatten", "require_full_log",
-    "StreamingMetrics", "node_rows", "tenant_rows", "format_table",
-    "to_chrome_trace", "write_chrome_trace", "validate_chrome_trace",
-    "to_prometheus", "validate_prometheus", "to_jsonl", "write_jsonl",
-    "explain_miss", "explain_energy",
-    "MECHANISMS", "Scenario", "neutralize", "ablate", "delta_ledger",
-    "profile_mechanisms", "mechanism_columns",
-    "RunDiff", "diff_runs",
-    "Rule", "Alert", "Watchdog", "standard_rules",
-]
+import importlib
+
+from repro.obs import tracer
+
+# the simulated views import the runtime, which imports repro.core; they
+# load on first use, so that repro.core can import the tracer
+_LAZY = {
+    "counterfactual": ("MECHANISMS", "Scenario", "ablate", "delta_ledger",
+                       "mechanism_columns", "neutralize",
+                       "profile_mechanisms"),
+    "diff": ("RunDiff", "diff_runs"),
+    "explain": ("explain_energy", "explain_miss"),
+    "export": ("to_chrome_trace", "to_jsonl", "to_prometheus",
+               "validate_chrome_trace", "validate_prometheus",
+               "write_chrome_trace", "write_jsonl"),
+    "metrics": ("StreamingMetrics", "format_table", "node_rows",
+                "tenant_rows"),
+    "spans": ("Span", "build_job_spans", "build_spans", "flatten",
+              "require_full_log"),
+    "watchdog": ("Alert", "Rule", "Watchdog", "standard_rules"),
+}
+_HOME = {name: mod for mod, names in _LAZY.items() for name in names}
+
+
+def __getattr__(name: str):
+    mod = _HOME.get(name)
+    if mod is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    value = getattr(importlib.import_module(f"{__name__}.{mod}"), name)
+    globals()[name] = value
+    return value
+
+
+__all__ = ["tracer", *_HOME]

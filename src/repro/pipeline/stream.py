@@ -21,6 +21,7 @@ from repro.core.sampling import (_DOMAIN_SAMPLER, _hash_uniform,
                                  _z_for_confidence, sample_blocks_soa)
 from repro.core.scheduler import plan_dvfs_arrays
 from repro.core.soa import BlockArrays, EstimateArrays, PlanArrays
+from repro.obs.tracer import count, span
 
 __all__ = ["PipelineConfig", "stream_estimates", "stream_estimates_tokens",
            "sample_token_rows", "token_chunk_estimates", "plan_estimates", "stream_plan",
@@ -94,6 +95,23 @@ def _iter_chunks(source, chunk_size: int) -> Iterator[dict]:
         yield chunk
 
 
+_END = object()
+
+
+def _pulls(chunks: Iterable, n_blocks) -> Iterator:
+    """The chunks of ``chunks``, each pulled inside an ``estimate.source``
+    span: a lazy source does its work there, not in the sampler's spans."""
+    it = iter(chunks)
+    while True:
+        with span("estimate.source"):
+            chunk = next(it, _END)
+            if chunk is not _END:
+                count(blocks=n_blocks(chunk))
+        if chunk is _END:
+            return
+        yield chunk
+
+
 def stream_estimates(source, config: PipelineConfig = PipelineConfig()
                      ) -> EstimateArrays:
     """Sampling stage: chunked per-record costs -> per-block ``EstimateArrays``.
@@ -104,16 +122,21 @@ def stream_estimates(source, config: PipelineConfig = PipelineConfig()
     """
     parts = []
     offset = 0
-    for chunk in _iter_chunks(source, config.chunk_size):
-        costs = np.asarray(chunk["costs"], dtype=np.float64)
-        est = sample_blocks_soa(
-            costs, chunk.get("lengths"), fraction=config.fraction,
-            min_samples=config.min_samples, n_boot=config.n_boot,
-            confidence=config.confidence, seed=config.seed,
-            start_index=offset, method=config.sampler)
-        parts.append(est)
-        offset += len(est)
-    return EstimateArrays.concat(parts)
+    # the request's root span; "pipeline." keeps it apart from a caller's
+    # own span around the call, such as a job's "estimate" step
+    with span("pipeline.estimate"):
+        for chunk in _pulls(_iter_chunks(source, config.chunk_size),
+                            lambda c: len(c["costs"])):
+            costs = np.asarray(chunk["costs"], dtype=np.float64)
+            est = sample_blocks_soa(
+                costs, chunk.get("lengths"), fraction=config.fraction,
+                min_samples=config.min_samples, n_boot=config.n_boot,
+                confidence=config.confidence, seed=config.seed,
+                start_index=offset, method=config.sampler)
+            parts.append(est)
+            offset += len(est)
+            count(blocks=len(est), records=int(est.n_records.sum()))
+        return EstimateArrays.concat(parts)
 
 
 def sample_token_rows(tokens: np.ndarray, *, start_index: int,
@@ -133,14 +156,18 @@ def sample_token_rows(tokens: np.ndarray, *, start_index: int,
     kmax = int(k.max()) if b else 0
     if kmax == 0:
         return np.zeros((b, 0, length), tokens.dtype), k
-    keys = _hash_uniform(config.seed, index[:, None],
-                         np.arange(r, dtype=np.int64)[None, :],
-                         domain=_DOMAIN_SAMPLER)
-    part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
-    order = np.argsort(np.take_along_axis(keys, part, axis=1), axis=1,
-                       kind="stable")
-    sel = np.take_along_axis(part, order, axis=1)
-    return np.take_along_axis(tokens, sel[:, :, None], axis=1), k
+    with span("sample.keys", keys=b * r):
+        keys = _hash_uniform(config.seed, index[:, None],
+                             np.arange(r, dtype=np.int64)[None, :],
+                             domain=_DOMAIN_SAMPLER)
+    with span("sample.select", rows=int(k.sum())):
+        part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
+        order = np.argsort(np.take_along_axis(keys, part, axis=1), axis=1,
+                           kind="stable")
+        sel = np.take_along_axis(part, order, axis=1)
+        sampled = np.take_along_axis(tokens, sel[:, :, None], axis=1)
+        count(bytes=sampled.nbytes)
+    return sampled, k
 
 
 def token_chunk_estimates(
@@ -185,32 +212,35 @@ def token_chunk_estimates(
         return EstimateArrays(index, z0, z0.copy(), z0.copy(), k,
                               np.full(b, r, dtype=np.int64))
 
-    # block-level sampled features: ONE fused kernel dispatch for the chunk
-    stats = np.asarray(ops.block_stats_batched(
-        sampled.astype(np.int32), k.astype(np.int32), tuple(pattern),
-        interpret=interpret), dtype=np.float64)
-    w = np.asarray(weights, dtype=np.float64)
-    mean_cost = (stats @ w) / k
+    # block-level sampled features: ONE fused kernel dispatch for the chunk;
+    # the span ends once the features are back on the host
+    with span("estimate.kernel", bytes=4 * (sampled.size + b)):
+        stats = np.asarray(ops.block_stats_batched(
+            sampled.astype(np.int32), k.astype(np.int32), tuple(pattern),
+            interpret=interpret), dtype=np.float64)
+    with span("sample.stats", rows=int(k.sum())):
+        w = np.asarray(weights, dtype=np.float64)
+        mean_cost = (stats @ w) / k
 
-    # per-row decomposition of the same features -> sample variance -> CI
-    nonpad_r = (sampled != 0).sum(axis=2)
-    mass_r = sampled.astype(np.float64).sum(axis=2)
-    p = len(pattern)
-    if length >= p:
-        hits = np.ones((b, kmax, length - p + 1), dtype=bool)
-        for j, pj in enumerate(pattern):
-            hits &= sampled[:, :, j:length - p + 1 + j] == pj
-        match_r = hits.sum(axis=2)
-    else:
-        match_r = np.zeros((b, kmax), dtype=np.int64)
-    cost_r = w[0] * nonpad_r + w[1] * match_r + w[2] * mass_r
-    valid = np.arange(kmax)[None, :] < k[:, None]
-    row_mean = np.where(valid, cost_r, 0.0).sum(axis=1) / k
-    var = (np.where(valid, cost_r - row_mean[:, None], 0.0) ** 2).sum(axis=1) \
-        / np.maximum(k - 1, 1)
-    se = np.sqrt(var / k)
-    hw = _z_for_confidence(config.confidence) * se * r
-    total = mean_cost * r
+        # per-row decomposition of the same features -> sample variance -> CI
+        nonpad_r = (sampled != 0).sum(axis=2)
+        mass_r = sampled.astype(np.float64).sum(axis=2)
+        p = len(pattern)
+        if length >= p:
+            hits = np.ones((b, kmax, length - p + 1), dtype=bool)
+            for j, pj in enumerate(pattern):
+                hits &= sampled[:, :, j:length - p + 1 + j] == pj
+            match_r = hits.sum(axis=2)
+        else:
+            match_r = np.zeros((b, kmax), dtype=np.int64)
+        cost_r = w[0] * nonpad_r + w[1] * match_r + w[2] * mass_r
+        valid = np.arange(kmax)[None, :] < k[:, None]
+        row_mean = np.where(valid, cost_r, 0.0).sum(axis=1) / k
+        var = (np.where(valid, cost_r - row_mean[:, None], 0.0) ** 2) \
+            .sum(axis=1) / np.maximum(k - 1, 1)
+        se = np.sqrt(var / k)
+        hw = _z_for_confidence(config.confidence) * se * r
+        total = mean_cost * r
     return EstimateArrays(index, total, total - hw, total + hw, k,
                           np.full(b, r, dtype=np.int64))
 
@@ -225,13 +255,15 @@ def stream_estimates_tokens(
 ) -> EstimateArrays:
     """Sampling stage over ``(start, tokens)`` chunks (e.g.
     ``BlockDataset.iter_token_chunks``)."""
-    parts = [
-        token_chunk_estimates(toks, start_index=start, config=config,
-                              pattern=pattern, weights=weights,
-                              interpret=interpret)
-        for start, toks in token_chunks
-    ]
-    return EstimateArrays.concat(parts)
+    parts = []
+    with span("pipeline.estimate"):
+        for start, toks in _pulls(token_chunks, lambda c: len(c[1])):
+            est = token_chunk_estimates(toks, start_index=start,
+                                        config=config, pattern=pattern,
+                                        weights=weights, interpret=interpret)
+            parts.append(est)
+            count(blocks=len(est), records=int(est.n_records.sum()))
+        return EstimateArrays.concat(parts)
 
 
 def plan_estimates(
@@ -254,22 +286,31 @@ def plan_estimates(
     ``CostFit.roofline()`` per block), a ``CounterTrace`` calibrates the
     node specs before the cluster plan.
     """
-    fit, trace = _split_calibration(config)
-    roofline = fit.roofline_arrays(est.n_records) if fit is not None else None
-    ba = est.to_block_arrays(util=util, roofline=roofline)
-    if nodes is not None:
-        from repro.cluster.planner import plan_cluster_arrays
-        return plan_cluster_arrays(ba, nodes, deadline_s,
-                                   assignment=assignment,
-                                   error_margin=config.error_margin,
-                                   power_cap_w=power_cap_w,
-                                   calibration=trace)
-    if power_cap_w is not None:
-        raise ValueError("power_cap_w needs a cluster plan (pass nodes)")
-    return plan_dvfs_arrays(ba, deadline_s, planner=config.planner,
-                            ladder=config.ladder, power=config.power,
-                            error_margin=config.error_margin,
-                            adaptive_margin=config.adaptive_margin)
+    # the record holds what a missed deadline is read from: the deadline,
+    # the summed estimate and the plan's own predicted time
+    with span("pipeline.plan", blocks=len(est), deadline_s=float(deadline_s),
+              est_s=float(est.total.sum())):
+        fit, trace = _split_calibration(config)
+        roofline = fit.roofline_arrays(est.n_records) if fit is not None \
+            else None
+        ba = est.to_block_arrays(util=util, roofline=roofline)
+        if nodes is not None:
+            from repro.cluster.planner import plan_cluster_arrays
+            plan = plan_cluster_arrays(ba, nodes, deadline_s,
+                                       assignment=assignment,
+                                       error_margin=config.error_margin,
+                                       power_cap_w=power_cap_w,
+                                       calibration=trace)
+            count(planned_s=plan.pred_makespan_s)
+            return plan
+        if power_cap_w is not None:
+            raise ValueError("power_cap_w needs a cluster plan (pass nodes)")
+        plan = plan_dvfs_arrays(ba, deadline_s, planner=config.planner,
+                                ladder=config.ladder, power=config.power,
+                                error_margin=config.error_margin,
+                                adaptive_margin=config.adaptive_margin)
+        count(planned_s=plan.pred_total_time)
+        return plan
 
 
 def stream_plan(
