@@ -210,6 +210,89 @@ def _hash_uniform(seed: int, block_index: np.ndarray, slot: np.ndarray,
     return out
 
 
+# slots the streamed selection hashes a tile: a power of two no larger than
+# 2**24, so a tile's counters (block << 24) ^ slot are one constant plus the
+# offsets 0.._TILE-1; 512 KiB of uint64 stays in the host's L2
+_TILE = 1 << 16
+# binomial standard deviations a block's key bound lies above k/n: at six,
+# fewer than k keys fall under it about once in 10**9 blocks (a refill)
+_TAU_SIGMAS = 6.0
+_U64_MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
+
+
+def _bounded_candidates(seed: int, index: np.ndarray, n: np.ndarray,
+                        k: np.ndarray) -> tuple:
+    """Each block's (slots, hashes) whose sampler key lies under its bound.
+
+    A key is ``_hash_uniform``'s, bit for bit: the splitmix64 hash ``h`` of
+    the block's counter, as ``(h >> 11) / 2**53``.  Block ``j`` (global
+    index ``index[j]``) hashes its ``n[j]`` slots a tile at a time in reused
+    buffers and keeps those whose key lies under ``tau = p + _TAU_SIGMAS *
+    sqrt(p (1 - p) / n)``, ``p = k / n``.  A block that keeps fewer than
+    ``k[j]`` is hashed again under a doubled bound (a refill) until it keeps
+    enough, so the ``k[j]`` smallest keys are always among its candidates.
+    Returns ``([(slots, hashes)] in slot order, one a block; refills)`` and
+    counts the slots hashed into the open span as ``keys``.
+    """
+    mix = ((int(seed) * 0x9E3779B97F4A7C15)
+           ^ (_DOMAIN_SAMPLER * 0xD1B54A32D192ED03 + 0x632BE59BD9B4E019))
+    ramp = np.arange(_TILE, dtype=np.uint64)
+    v = np.empty(_TILE, dtype=np.uint64)
+    t = np.empty(_TILE, dtype=np.uint64)
+    under = np.empty(_TILE, dtype=bool)
+    found, hashed, refills = [], 0, 0
+    for block, nj, kj in zip(index.tolist(), n.tolist(), k.tolist()):
+        p = kj / max(nj, 1)
+        tau = p + _TAU_SIGMAS * np.sqrt(p * (1.0 - p) / max(nj, 1))
+        while True:
+            # inclusive limit on h: key < tau  <=>  h < ceil(tau 2**53) << 11
+            limit = _U64_MAX if tau >= 1.0 else \
+                np.uint64(max((int(np.ceil(tau * 2.0 ** 53)) << 11) - 1, 0))
+            slots, hashes = [], []
+            for s in range(0, nj, _TILE):
+                m = min(_TILE, nj - s)
+                vt, tt = v[:m], t[:m]
+                np.add(ramp[:m], np.uint64((((block << 24) ^ s) + mix)
+                                           & 0xFFFFFFFFFFFFFFFF), out=vt)
+                np.right_shift(vt, np.uint64(30), out=tt)
+                vt ^= tt
+                vt *= _SM64_MULT1
+                np.right_shift(vt, np.uint64(27), out=tt)
+                vt ^= tt
+                vt *= _SM64_MULT2
+                np.right_shift(vt, np.uint64(31), out=tt)
+                vt ^= tt
+                np.less_equal(vt, limit, out=under[:m])
+                kept = np.flatnonzero(under[:m])
+                slots.append(kept + s)
+                hashes.append(vt[kept])
+            hashed += nj
+            if sum(len(h) for h in hashes) >= kj:
+                break
+            refills += 1
+            tau = min(1.0, 2.0 * max(tau, p))
+        found.append((np.concatenate(slots or [np.zeros(0, np.int64)]),
+                      np.concatenate(hashes or [np.zeros(0, np.uint64)])))
+    count(keys=hashed)
+    return found, refills
+
+
+def _smallest(found: list, k: np.ndarray) -> np.ndarray:
+    """(b, kmax) slots: block ``j``'s ``k[j]`` candidates of smallest hash,
+    in slot order, padded with slot 0 past ``k[j]``.
+
+    A block's hashes are distinct (the finalizer is a bijection), so the
+    set is exact; keys tie only where hashes agree in their top 53 bits,
+    and the hash's low bits break that tie.
+    """
+    sel = np.zeros((len(k), int(k.max())), dtype=np.int64)
+    for j, ((slots, h), kj) in enumerate(zip(found, k.tolist())):
+        if kj:
+            kth = np.partition(h, kj - 1)[kj - 1]
+            sel[j, :kj] = slots[h <= kth]
+    return sel
+
+
 def sample_blocks_soa(
     costs: np.ndarray,
     lengths: np.ndarray | None = None,
@@ -238,7 +321,11 @@ def sample_blocks_soa(
     ``n_boot × k`` work per block is what the object path spends most of its
     time on, and at a million blocks it alone would cost minutes.  Degenerate
     blocks are safe by construction: single-record and zero-variance blocks
-    get a zero-width CI, empty blocks a zero estimate — never NaN.
+    get a zero-width CI, empty blocks a zero estimate — never NaN.  Where
+    blocks span at least one ``_TILE`` of records and the sample is at most
+    half of them, no whole-block array of keys is built: each block's slots
+    are hashed a tile at a time and only those under a key bound are ranked
+    (``_bounded_candidates``), which picks the same slots.
 
     ``method="exact"`` reproduces ``sample_blocks`` bit for bit (same
     per-block ``SeedSequence((seed, global_index))`` streams, same bootstrap
@@ -285,31 +372,42 @@ def sample_blocks_soa(
         z0 = np.zeros(b)
         return EstimateArrays(index, z0, z0.copy(), z0.copy(),
                               k, n)
-    with span("sample.keys", keys=b * r):
-        slots = np.arange(r, dtype=np.int64)
-        keys = _hash_uniform(seed, index[:, None], slots[None, :],
-                             domain=_DOMAIN_SAMPLER)
     uniform = lengths is None and int(k.min()) == kmax
     rows = int(k.sum())
-    with span("sample.select", rows=rows):
-        if not uniform:
-            keys = np.where(slots[None, :] < n[:, None], keys, np.inf)
-        # exact without-replacement sample: each block's k smallest keys
-        if kmax < r:
-            part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
-        else:
-            part = np.broadcast_to(slots[None, :], (b, r))
-        if uniform:
-            # every block samples exactly kmax records: the k-smallest SET is
-            # all that matters for mean/variance, so skip the within-row
-            # sort+mask
-            sampled = np.take_along_axis(costs, part, axis=1)
-        else:
-            order = np.argsort(np.take_along_axis(keys, part, axis=1),
-                               axis=1, kind="stable")
-            sel = np.take_along_axis(part, order, axis=1)
+    if r >= _TILE and 2 * kmax <= r:
+        # large blocks, a small sample: hash in cache-sized tiles and rank
+        # only the slots under each block's key bound
+        with span("sample.keys"):
+            found, refills = _bounded_candidates(seed, index, n, k)
+        with span("sample.select", rows=rows,
+                  candidates=sum(len(h) for _, h in found), refills=refills):
+            sel = _smallest(found, k)
             sampled = np.take_along_axis(costs, sel, axis=1)
-        count(bytes=sampled.nbytes)
+            count(bytes=sampled.nbytes)
+    else:
+        with span("sample.keys", keys=b * r):
+            slots = np.arange(r, dtype=np.int64)
+            keys = _hash_uniform(seed, index[:, None], slots[None, :],
+                                 domain=_DOMAIN_SAMPLER)
+        with span("sample.select", rows=rows, candidates=b * r, refills=0):
+            if not uniform:
+                keys = np.where(slots[None, :] < n[:, None], keys, np.inf)
+            # exact without-replacement sample: each block's k smallest keys
+            if kmax < r:
+                part = np.argpartition(keys, kmax - 1, axis=1)[:, :kmax]
+            else:
+                part = np.broadcast_to(slots[None, :], (b, r))
+            if uniform:
+                # every block samples exactly kmax records: the k-smallest
+                # SET is all that matters for mean/variance, so skip the
+                # within-row sort+mask
+                sampled = np.take_along_axis(costs, part, axis=1)
+            else:
+                order = np.argsort(np.take_along_axis(keys, part, axis=1),
+                                   axis=1, kind="stable")
+                sel = np.take_along_axis(part, order, axis=1)
+                sampled = np.take_along_axis(costs, sel, axis=1)
+            count(bytes=sampled.nbytes)
     with span("sample.stats", rows=rows):
         if uniform:
             mean = sampled.mean(axis=1)

@@ -231,6 +231,34 @@ def test_token_front_spans_nest_in_estimate_in_a_profiler_trace(tmp_path):
         assert all(s0 <= s <= e <= e0 for _, s, e in inner), name
 
 
+@pytest.mark.parametrize("sigmas, refills", [(6.0, 0), (-6.0, 2)],
+                         ids=["uniform chunk", "bound too low"])
+def test_key_bound_counts_candidates_and_refills(sigmas, refills,
+                                                 monkeypatch):
+    """Blocks of at least a tile: ``sample.select`` counts the slots under
+    the key bound (at least the rows kept, a few more) and the blocks
+    hashed again; ``sample.keys`` every slot hashed, refills included."""
+    from repro.core import sampling
+    from repro.pipeline import PipelineConfig, stream_estimates
+
+    monkeypatch.setattr(sampling, "_TAU_SIGMAS", sigmas)
+    r = 1 << 17
+    costs = np.random.default_rng(1).random((2, r))
+    lo = time.perf_counter_ns()
+    stream_estimates(costs, PipelineConfig(chunk_size=2, seed=2**31 + 5))
+    recs = tracer.records(lo)
+    keys = [rec.counts for rec in recs if rec.name == "sample.keys"]
+    select = [rec.counts for rec in recs if rec.name == "sample.select"]
+    assert keys == [{"keys": (2 + refills) * r}]
+    assert len(select) == 1
+    c = select[0]
+    assert c["rows"] == 2 * 6554 and c["refills"] == refills
+    assert c["rows"] <= c["candidates"]
+    # six binomial sigmas above k: ~7 % more than the rows at this size
+    assert c["candidates"] <= 1.1 * c["rows"] or refills
+    assert c["candidates"] < 2 * r // 5
+
+
 def test_importing_the_core_loads_neither_jax_nor_the_runtime():
     """The recorder imports JAX on its first span, not with ``repro.core``;
     ``repro.obs`` loads its simulated views on first use."""

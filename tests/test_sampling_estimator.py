@@ -125,3 +125,78 @@ def test_sample_blocks_soa_degenerate_blocks():
     assert est.total[1] == 9.0 and est.n_sampled[1] == 1
     assert est.total[2] == 0.0 and est.n_sampled[2] == 0
     assert np.all(est.rel_halfwidth >= 0.0)
+
+
+def _whole_array_sample(seed, index, n, k, r):
+    """Each block's k smallest-key slots, ascending, as the sampler picks
+    them from the whole (b, r) key array: ``_hash_uniform``, then
+    ``argpartition``."""
+    from repro.core.sampling import _DOMAIN_SAMPLER, _hash_uniform
+
+    keys = _hash_uniform(seed, index[:, None], np.arange(r)[None, :],
+                         domain=_DOMAIN_SAMPLER)
+    keys = np.where(np.arange(r)[None, :] < n[:, None], keys, np.inf)
+    return [np.sort(np.argpartition(keys[j], k[j] - 1)[:k[j]]) if k[j]
+            else np.zeros(0, dtype=np.int64) for j in range(len(n))]
+
+
+# (b, r, lengths, fraction, min_samples, _TAU_SIGMAS, refills a block)
+_SAMPLE_SET_CASES = {
+    "uniform blocks": (3, 1 << 17, None, 0.05, 16, 6.0, 0),
+    "ragged, empty and one-record blocks":
+        (5, 1 << 17, [1 << 17, 70_000, 0, 1, 100_001], 0.05, 16, 6.0, 0),
+    "k == n": (3, 5000, [5000, 17, 1], 1.0, 16, 6.0, 0),
+    "min_samples above fraction n": (2, 1 << 17, None, 0.001, 2000, 6.0, 0),
+    "r below one tile": (4, 1000, None, 0.05, 16, 6.0, 0),
+    "bound too low: a refill": (2, 1 << 17, None, 0.05, 16, -6.0, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(_SAMPLE_SET_CASES))
+def test_streamed_selection_is_the_whole_array_sample(case, monkeypatch):
+    """The tiled, key-bound selection picks each block's slots as hashing
+    and partitioning the whole (b, r) key array does, and a bound that
+    keeps too few is refilled.  ``sample_blocks_soa`` then matches the
+    whole-array arithmetic within 1e-12, however the blocks are chunked."""
+    from repro.core import sampling
+    from repro.core.soa import EstimateArrays
+
+    b, r, lengths, fraction, min_samples, sigmas, refills = \
+        _SAMPLE_SET_CASES[case]
+    monkeypatch.setattr(sampling, "_TAU_SIGMAS", sigmas)
+    seed, start = 2**31 + 19, 7
+    index = start + np.arange(b)
+    n = np.full(b, r) if lengths is None else np.asarray(lengths)
+    k = np.minimum(n, np.maximum(min_samples,
+                                 np.ceil(fraction * n).astype(np.int64)))
+    want = _whole_array_sample(seed, index, n, k, r)
+
+    found, refilled = sampling._bounded_candidates(seed, index, n, k)
+    assert refilled == refills * int(np.count_nonzero(k))
+    for j, (slots, h) in enumerate(found):
+        assert np.all(slots < n[j]) and len(slots) >= k[j]
+    sel = sampling._smallest(found, k)
+    for j in range(b):
+        assert np.array_equal(sel[j, :k[j]], want[j])
+
+    costs = np.random.default_rng(5).lognormal(0.0, 0.7, (b, r))
+    est = sampling.sample_blocks_soa(costs, lengths, fraction=fraction,
+                                     min_samples=min_samples, seed=seed,
+                                     start_index=start)
+    assert np.array_equal(est.n_sampled, k)
+    one_a_chunk = [sampling.sample_blocks_soa(
+        costs[j:j + 1], None if lengths is None else n[j:j + 1],
+        fraction=fraction, min_samples=min_samples, seed=seed,
+        start_index=start + j) for j in range(b)]
+    with monkeypatch.context() as whole:   # every block below one tile
+        whole.setattr(sampling, "_TILE", 1 << 30)
+        ref = sampling.sample_blocks_soa(costs, lengths, fraction=fraction,
+                                         min_samples=min_samples, seed=seed,
+                                         start_index=start)
+    # a ragged chunk's masked sums run over its widest sample, so only the
+    # order of summation depends on the chunk
+    for name in ("total", "ci_low", "ci_high"):
+        got = getattr(est, name)
+        for other in (ref, EstimateArrays.concat(one_a_chunk)):
+            np.testing.assert_allclose(getattr(other, name), got, rtol=1e-12,
+                                       atol=0)
