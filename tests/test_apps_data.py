@@ -2,8 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.apps import ALL_APPS
+from repro.apps.aggregate import _group_sums
 from repro.core import variety_stats, zipf_block_sizes, zipf_weights
 from repro.data import BlockDataset, pack_tokens
 
@@ -54,19 +56,58 @@ def test_inverted_index_oracle():
         assert got == want
 
 
-def test_avg_sum_oracle():
-    ds = BlockDataset(n_blocks=1, records_per_block=256, max_len=16, seed=4)
-    b = ds.block(0)
-    jb = _jnp_block(b)
-    avg = np.asarray(jax.jit(ALL_APPS["avg"]().run)(jb))
-    tot = np.asarray(jax.jit(ALL_APPS["sum"]().run)(jb))
-    v, g, s = b["values"], b["group"], b["select"]
-    for gi in range(8):
-        m = (g == gi) & s
-        ref_sum = v[m].sum()
-        ref_avg = ref_sum / max(m.sum(), 1)
-        np.testing.assert_allclose(tot[gi], ref_sum, rtol=1e-5)
-        np.testing.assert_allclose(avg[gi], ref_avg, rtol=1e-5)
+def _agg_block(case):
+    """(values, group, select) of one ``test_avg_sum_oracle`` case."""
+    if case == "block":
+        b = BlockDataset(n_blocks=1, records_per_block=256, max_len=16,
+                         seed=4).block(0)
+        return b["values"], b["group"], b["select"]
+    rng = np.random.default_rng(5)
+    n = 1 << 22 if case == "q1_shares" else 4096
+    v = rng.uniform(900.0, 105_000.0, n).astype(np.float32)
+    g = rng.integers(0, 8, n).astype(np.int32)
+    s = rng.random(n) < 0.9
+    if case == "none_selected":
+        s[:] = False
+    elif case == "group_empty":
+        g[g == 3] = 5
+    elif case == "ids_out_of_range":
+        g[::7] = -1
+        g[3::7] = 8
+        g[5::11] = np.iinfo(np.int32).max
+    elif case == "q1_shares":  # TPC-H Q1's groups and ~98.6 % selected
+        g = rng.choice(4, n, p=[0.25, 0.0066, 0.494, 0.2494]).astype(np.int32)
+        s = rng.random(n) < 0.986
+    return v, g, s
+
+
+@pytest.mark.parametrize("case", ["block", "none_selected", "group_empty",
+                                  "ids_out_of_range", "q1_shares"])
+@pytest.mark.parametrize("app", ["avg", "sum"])
+def test_avg_sum_oracle(app, case):
+    v, g, s = _agg_block(case)
+    keep = s & (g >= 0) & (g < 8)
+    ref_sum = np.bincount(g[keep], v[keep].astype(np.float64), minlength=8)
+    ref_cnt = np.bincount(g[keep], minlength=8)
+    jb = _jnp_block({"values": v, "group": g, "select": s})
+    sums, cnts = jax.jit(lambda b: _group_sums(b, 8))(jb)
+    assert cnts.dtype == jnp.int32 and sums.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(cnts), ref_cnt)
+    out = jax.jit(ALL_APPS[app]().run)(jb)
+    assert out.shape == (8,) and out.dtype == jnp.float32
+    want = ref_sum if app == "sum" else ref_sum / np.maximum(ref_cnt, 1)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=0)
+
+
+@pytest.mark.parametrize("app", ["avg", "sum"])
+def test_avg_sum_no_scatter(app):
+    n = 1000
+    blk = {"values": jnp.zeros(n, jnp.float32),
+           "group": jnp.zeros(n, jnp.int32), "select": jnp.ones(n, bool)}
+    hlo = jax.jit(ALL_APPS[app]().run).lower(blk).as_text()
+    assert "scatter" not in hlo
+    # float32 all through: no contraction at the MXU's default precision
+    assert "dot_general" not in hlo and "bf16" not in hlo
 
 
 def test_blocks_deterministic():
