@@ -6,7 +6,7 @@ Prints ``name,us_per_call,derived`` CSV rows (scaffold contract).  Sections:
              model AND the TPU-adapted model), firm deadline
   fig11-12 — Zipf sensitivity z ∈ {0,1,2}
   fig13    — tight vs firm deadline
-  planners — paper vs global vs roofline planner on the same workload
+  planners — paper vs global planner on the same workload
   planner_scale — vectorized planning/sampling hot path at 100 .. 100k
              blocks: blocks/sec per planner, speedup vs the loop reference
              at 10k, plan-equivalence asserts at small n, batched sampler
@@ -742,8 +742,10 @@ def bench_obs(quick: bool = False):
     """
     import dataclasses
 
-    from repro import obs
     from repro.cluster.planner import plan_cluster_arrays
+    from repro.obs.export import to_chrome_trace, validate_chrome_trace
+    from repro.obs.metrics import StreamingMetrics
+    from repro.obs.spans import build_spans
     from repro.runtime import run_cluster
 
     rows = []
@@ -755,7 +757,7 @@ def bench_obs(quick: bool = False):
         for engine in ("vector", "scalar"):
             base_wall = None
             for metrics in ("off", "on"):
-                mx = obs.StreamingMetrics() if metrics == "on" else None
+                mx = StreamingMetrics() if metrics == "on" else None
                 c = dataclasses.replace(cfg, metrics=mx)
                 t0 = time.perf_counter()
                 rep = run_cluster(plan, blocks, config=c, events=events,
@@ -786,7 +788,7 @@ def bench_obs(quick: bool = False):
                       engine="vector")
     n_rows = len(rep.event_log)
     t0 = time.perf_counter()
-    spans = obs.build_spans(rep.event_log)
+    spans = build_spans(rep.event_log)
     span_wall = time.perf_counter() - t0
     rows.append({"scenario": "spans", "stage": "build_spans", "n": n,
                  "nodes": k, "engine": "vector", "events": "full",
@@ -795,9 +797,9 @@ def bench_obs(quick: bool = False):
     _row("obs_build_spans", span_wall * 1e6 / n,
          f"rows_per_s={n_rows / span_wall:,.0f};log_rows={n_rows}")
     t0 = time.perf_counter()
-    doc = obs.to_chrome_trace(rep, spans=spans)
+    doc = to_chrome_trace(rep, spans=spans)
     export_wall = time.perf_counter() - t0
-    assert obs.validate_chrome_trace(doc) == []
+    assert validate_chrome_trace(doc) == []
     rows.append({"scenario": "spans", "stage": "chrome_export", "n": n,
                  "nodes": k, "engine": "vector", "events": "full",
                  "wall_s": export_wall, "blocks_per_s": n / export_wall,
@@ -829,19 +831,22 @@ def bench_obs_cf(quick: bool = False):
     import dataclasses
     import math
 
-    from repro import obs
     from repro.cluster.planner import plan_cluster_arrays
+    from repro.obs.counterfactual import Scenario, ablate, profile_mechanisms
+    from repro.obs.diff import diff_runs
+    from repro.obs.metrics import StreamingMetrics
+    from repro.obs.watchdog import Watchdog, standard_rules
     from repro.runtime import run_cluster
 
     rows = []
     n, k = (2_000, 8) if quick else (10_000, 8)
     blocks, nodes, deadline, events, cfg = _fleet_scenario(n, k, 0.02)
     plan = plan_cluster_arrays(blocks, nodes, deadline_s=deadline)
-    sc = obs.Scenario(plan=plan, truth=blocks, config=cfg, events=events)
+    sc = Scenario(plan=plan, truth=blocks, config=cfg, events=events)
 
     # --- ablation grid: both engines, exact Δ reconciliation ----------------
     t0 = time.perf_counter()
-    cf = obs.profile_mechanisms(sc)
+    cf = profile_mechanisms(sc)
     cf_wall = time.perf_counter() - t0
     n_runs = 2 * (1 + sum(r["changed"] for r in cf))
     chans = ("busy_j", "idle_j", "switch_j", "wire_j", "failed_j")
@@ -865,10 +870,10 @@ def bench_obs_cf(quick: bool = False):
     # make "at equal deadline" vacuous): DV-DVFS must meet the deadline
     # AND pay strictly less busy energy than its own f_max replay
     hd_plan = plan_cluster_arrays(blocks, nodes, deadline_s=deadline * 1.2)
-    hd = obs.Scenario(plan=hd_plan, truth=blocks, config=cfg)
+    hd = Scenario(plan=hd_plan, truth=blocks, config=cfg)
     t0 = time.perf_counter()
     hd_base = hd.run(engine="vector")
-    hd_fmax = obs.ablate(hd, "dvfs", engines=("vector",))
+    hd_fmax = ablate(hd, "dvfs", engines=("vector",))
     hd_wall = time.perf_counter() - t0
     d_busy = hd_fmax.total_energy_j - hd_base.total_energy_j
     assert d_busy > 0.0, \
@@ -889,8 +894,8 @@ def bench_obs_cf(quick: bool = False):
     base_total = cf[0]["base_total_j"]    # every ledger row carries it
 
     def wd_run(engine):
-        mx = obs.StreamingMetrics()
-        wd = obs.Watchdog(obs.standard_rules(
+        mx = StreamingMetrics()
+        wd = Watchdog(standard_rules(
             deadline, energy_budget_j=0.8 * base_total)).attach(mx)
         run_cluster(plan, blocks,
                     config=dataclasses.replace(wcfg, metrics=mx),
@@ -917,10 +922,10 @@ def bench_obs_cf(quick: bool = False):
     t0 = time.perf_counter()
     rep_a = sc_full.run(engine="vector")
     rep_b = sc_full.run(engine="vector")
-    assert obs.diff_runs(rep_a, rep_b).empty, \
+    assert diff_runs(rep_a, rep_b).empty, \
         "diff of two identical runs must be empty"
-    abl = obs.ablate(sc_full, "migration", engines=("vector",))
-    diff = obs.diff_runs(rep_a, abl)
+    abl = ablate(sc_full, "migration", engines=("vector",))
+    diff = diff_runs(rep_a, abl)
     diff_wall = time.perf_counter() - t0
     assert not diff.empty and (diff.moved or diff.blocks), \
         "migration-off diff must attribute changed work"
@@ -1508,7 +1513,7 @@ def bench_serve():
     rt = RooflineTimeModel.from_counts(flops=1e9, hbm_bytes=8e9, coll_bytes=0)
     eng = ServingEngine(cfg, params,
                         ServeConfig(batch=2, max_len=256, window=8,
-                                    planner="roofline", slack=1.1), roofline=rt)
+                                    planner="global", slack=1.1), roofline=rt)
     prompts = {"tokens": jnp.asarray(
         np.random.default_rng(0).integers(1, cfg.vocab, (2, 32)), jnp.int32)}
     t0 = time.perf_counter()

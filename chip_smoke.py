@@ -277,15 +277,17 @@ def phase_apps(toks, sz: Sizes, seed: int):
             f"({res['energy_improvement']:+.2%})")
 
 
-def phase_stream_run(est, sz: Sizes, seed: int):
+def phase_stream_run(est, sz: Sizes):
     """The streamed estimate -> cluster plan -> event-driven run."""
-    from repro.cluster import NodeSpec
-    from repro.pipeline import PipelineConfig, stream_run
+    from repro.cluster import NodeSpec, plan_cluster_arrays
+    from repro.runtime import RuntimeConfig, run_cluster
 
     speeds = (1.0, 1.0, 0.8, 1.25, 1.0, 0.9, 1.1, 1.0)[:sz.nodes]
     nodes = [NodeSpec(f"node{i}", speed=s) for i, s in enumerate(speeds)]
     deadline = float(est.total.sum()) / sum(speeds) * 1.5
-    rep = stream_run(est, deadline, PipelineConfig(seed=seed), nodes=nodes)
+    ba = est.to_block_arrays()
+    rep = run_cluster(plan_cluster_arrays(ba, nodes, deadline), ba,
+                      config=RuntimeConfig(log_events=False))
     check(rep.deadline_met and not rep.missed_blocks,
           "streamed cluster run missed its deadline")
     log(f"[phase1] stream_run over {len(nodes)} simulated nodes: "
@@ -306,7 +308,7 @@ def phase_pipeline(sz: Sizes, seed: int, on_tpu: bool):
         f"generated in {time.perf_counter() - t0:.1f} s")
     est = phase_estimate(ds, toks, sz, seed, on_tpu)
     phase_apps(toks, sz, seed)
-    phase_stream_run(est, sz, seed)
+    phase_stream_run(est, sz)
 
 
 # ----------------------------------------------------------------- phase 2 --

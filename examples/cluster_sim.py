@@ -32,7 +32,8 @@ import numpy as np
 from repro.cluster import (NodeSpec, SlowdownEvent, assign_blocks,
                            plan_cluster, plan_independent, simulate_cluster)
 from repro.core import BlockInfo, FrequencyLadder, zipf_block_sizes
-from repro.obs import StreamingMetrics, format_table, node_rows, tenant_rows
+from repro.obs.metrics import (StreamingMetrics, format_table, node_rows,
+                               tenant_rows)
 from repro.runtime import (CheckpointModel, MigrationModel, NodeFailureEvent,
                            RecoveryPolicy, RuntimeConfig, run_cluster)
 
@@ -234,8 +235,9 @@ def counterfactual_demo():
     print("=== 6) Counterfactuals: what did each mechanism buy, exactly ===")
     import dataclasses
 
-    from repro.obs import (Scenario, Watchdog, mechanism_columns,
-                           profile_mechanisms, standard_rules)
+    from repro.obs.counterfactual import (Scenario, mechanism_columns,
+                                          profile_mechanisms)
+    from repro.obs.watchdog import Watchdog, standard_rules
     from repro.runtime import ActuationModel
 
     ladder = FrequencyLadder((0.6, 0.8, 1.0))

@@ -1,8 +1,9 @@
 """Batched serving with DV-DVFS slot scheduling.
 
 Decode on TPU-class hardware is memory-bandwidth-bound — exactly the regime
-where the roofline planner harvests FREE energy savings: the clock drops to
-the zero-cost point without hurting the token SLO (DESIGN.md §7.1).
+where the planner harvests FREE energy savings off the decode roofline: the
+clock drops to the zero-cost point without hurting the token SLO
+(DESIGN.md §7.1).
 
 Run:  PYTHONPATH=src python examples/serve_batch.py --tokens 64
 """
@@ -23,8 +24,8 @@ def main():
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=64)
-    ap.add_argument("--planner", default="roofline",
-                    choices=["paper", "global", "roofline"])
+    ap.add_argument("--planner", default="global",
+                    choices=["paper", "global"])
     args = ap.parse_args()
 
     cfg = smoke_config(args.arch)

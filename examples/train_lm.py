@@ -37,7 +37,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     ap.add_argument("--no-dvfs", action="store_true")
     ap.add_argument("--planner", default="paper",
-                    choices=["paper", "global", "roofline"])
+                    choices=["paper", "global"])
     args = ap.parse_args()
 
     cfg, sizes = make_cfg(args.preset)

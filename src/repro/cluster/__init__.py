@@ -33,7 +33,7 @@ Offline (``plan_cluster``)::
 Online (``OnlineReplanner`` inside ``simulate_cluster(..., online=True)``)::
 
     4  OBSERVE  each finished block's wall time; ratio = observed / base
-                prediction feeds the straggler EWMA (repro.train.straggler)
+                prediction feeds the straggler EWMA (repro.cluster.straggler)
                 -> per-node drift estimate + straggler events
     5  REPLAN   when |drift / drift_at_last_plan - 1| > threshold and blocks
                 remain: re-estimate the node's tail (base est x drift),

@@ -7,7 +7,7 @@ controller closes the loop *between blocks*:
   observe      each finished block reports its wall time; the controller
                compares it with the *base* (undrifted) prediction at the
                frequency actually run and feeds the ratio into the same EWMA
-               machinery as ``repro.train.straggler.StragglerDetector`` — the
+               machinery as ``repro.cluster.straggler.StragglerDetector`` — the
                EWMA mean of observed/predicted IS the node's drift estimate,
                and the z-score/budget logic flags straggler blocks for free.
 
@@ -32,7 +32,7 @@ import numpy as np
 from repro.core.scheduler import BlockInfo, BlockPlan, plan_dvfs_arrays
 from repro.core.soa import BlockArrays
 from repro.cluster.planner import ClusterPlan
-from repro.train.straggler import StragglerDetector
+from repro.cluster.straggler import StragglerDetector
 
 __all__ = ["OnlineReplanner"]
 

@@ -2,7 +2,7 @@
 
 Pipeline (paper Fig. 3/4):  blocks -> sampling -> estimator -> frequency planner ->
 execution (+ energy accounting) — with a Data-Variety-Oblivious (DVO) baseline and
-beyond-paper global/roofline planners (DESIGN.md §7).
+the beyond-paper global planner, roofline-aware per block (DESIGN.md §7).
 """
 from repro.core.energy import (CPU_PAPER_POWER, DEFAULT_LADDER, TPU_V5E_POWER,
                                FrequencyLadder, PowerModel)

@@ -95,10 +95,8 @@ def plan_dvfs_reference(
     n = len(blocks)
     if n == 0:
         return SchedulePlan(planner, deadline_s, (), True)
-    if planner not in ("paper", "global", "slack_pool", "roofline"):
+    if planner not in ("paper", "global"):
         raise ValueError(f"unknown planner: {planner}")
-    if planner == "slack_pool":  # historical alias
-        planner = "global"
 
     slot = deadline_s / n  # Algorithm 1 line 3: equal time slots
 
@@ -148,7 +146,7 @@ def plan_dvfs_reference(
         feasible = total_t <= deadline_s + 1e-9
         return SchedulePlan("paper", deadline_s, tuple(plans), feasible)
 
-    # --- global greedy ("global" / "roofline") ------------------------------
+    # --- global greedy ---------------------------------------------------------
     states = ladder.states
     pos = [len(states) - 1 for _ in blocks]  # index into ladder per block
     times = [block_time(b, 1.0) for b in blocks]

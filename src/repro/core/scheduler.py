@@ -15,11 +15,13 @@ Planners:
                   with the best energy-saved / time-added ratio while the total still
                   fits the deadline (minus margin).  Strictly dominates equal slots at
                   tight deadlines.
-  * ``roofline``— beyond-paper TPU adaptation: ``global`` driven by per-block roofline
-                  time models ``PT(f) = max(T_comp·f_max/f, T_mem, T_coll)``.  Memory/
-                  collective-bound blocks down-clock to their zero-cost point for FREE
-                  (Δtime = 0), so the greedy takes those first.
   * DVO baseline — Data-Variety-Oblivious: f_max everywhere (paper's comparison).
+
+Both planners price each block off its own time model: a block that carries a
+roofline (``PT(f) = max(T_comp·f_max/f, T_mem, T_coll)``, the TPU adaptation)
+down-clocks to its zero-cost point for FREE (Δtime = 0) when it is memory- or
+collective-bound, so the greedy takes those steps first; a block without one
+scales as ``PT·f_max/f``.
 
 Hot path
 ========
@@ -250,8 +252,7 @@ def _downclock_sorted_scan(times_tab: np.ndarray, energies_tab: np.ndarray,
                            pos: np.ndarray, times: np.ndarray,
                            energies: np.ndarray, stop: np.ndarray,
                            group_total: np.ndarray,
-                           group_budget: np.ndarray,
-                           exact: bool = True) -> bool:
+                           group_budget: np.ndarray) -> bool:
     """Single-pool greedy as one sorted pass (returns False when inapplicable).
 
     When every item's ΔE/Δt keys are monotone along its descent chain
@@ -322,21 +323,6 @@ def _downclock_sorted_scan(times_tab: np.ndarray, energies_tab: np.ndarray,
     nondecr = keys[1:] >= keys[:-1]
     if not np.all(nondecr | (idx[1:] != idx[:-1])):
         return False  # non-monotone chain: heap order != sort order
-    if not exact:
-        # bucketed-key mode: quantize the float keys into ~1024 integer
-        # ranks.  Floor quantization is monotone, so the per-chain
-        # nondecreasing property (just verified) survives, and the integer
-        # keys sort via radix instead of comparison.  Steps in one bucket
-        # resolve in (item, chain position) order — still deterministic,
-        # but in-bucket the greedy's exact ratio order is given up, which
-        # can cost at most one bucket width of ΔE/Δt optimality per accept
-        # (the budget itself is still respected exactly).
-        lo = float(keys.min())
-        step = (float(keys.max()) - lo) / 1024.0
-        if step > 0.0:
-            keys = np.floor((keys - lo) / step).astype(np.int64)
-        else:
-            keys = np.zeros(len(keys), dtype=np.int64)
 
     total = float(group_total[0])
     budget = float(group_budget[0])
@@ -420,8 +406,7 @@ def _run_downclock_tables(times_tab: np.ndarray, energies_tab: np.ndarray,
                           pos: np.ndarray, times: np.ndarray,
                           energies: np.ndarray, group: np.ndarray,
                           group_total: np.ndarray,
-                          group_budget: np.ndarray,
-                          exact: bool = True) -> None:
+                          group_budget: np.ndarray) -> None:
     """Shared ΔE/Δt greedy core over precomputed tables (single-node + cluster).
 
     Exact table-driven analogue of the callback greedy in
@@ -458,8 +443,7 @@ def _run_downclock_tables(times_tab: np.ndarray, energies_tab: np.ndarray,
         # budget-binding single pool: the sorted-scan path resolves the bulk
         # of the greedy with array ops when it is provably heap-equivalent
         if _downclock_sorted_scan(times_tab, energies_tab, pos, times,
-                                  energies, stop, group_total, group_budget,
-                                  exact=exact):
+                                  energies, stop, group_total, group_budget):
             return
     else:
         # per-pool budgets are independent: a step's acceptance reads only
@@ -478,7 +462,7 @@ def _run_downclock_tables(times_tab: np.ndarray, energies_tab: np.ndarray,
                                   sub_pos, sub_t, sub_e,
                                   np.zeros(len(sel), dtype=np.int64),
                                   group_total[g:g + 1],
-                                  group_budget[g:g + 1], exact=exact)
+                                  group_budget[g:g + 1])
             pos[sel] = sub_pos
             times[sel] = sub_t
             energies[sel] = sub_e
@@ -526,7 +510,6 @@ def plan_dvfs_arrays(
     power: PowerModel = TPU_V5E_POWER,
     error_margin: float = 0.05,
     adaptive_margin: bool = False,
-    exact: bool = True,
 ) -> PlanArrays:
     """``plan_dvfs`` over SoA inputs: ``BlockArrays`` in, ``PlanArrays`` out.
 
@@ -534,22 +517,14 @@ def plan_dvfs_arrays(
     streamed-pipeline planner entry (``repro.pipeline``).  ``plan_dvfs`` is a
     thin wrapper over this function, so the two paths produce identical
     plans by construction.
-
-    ``exact=False`` relaxes the global-greedy sorted scan's key sort to
-    ~1024 integer buckets (radix-sortable) in the tight-deadline regime —
-    same feasibility guarantee and deterministic output, energy within a
-    bucket width of the exact greedy per step (``tests/test_scheduler.py``
-    pins the bound).  The "paper" planner ignores it (no sorted scan).
     """
     n = len(ba)
     if n == 0:
         e = np.zeros(0)
         return PlanArrays(planner, deadline_s, deadline_s, ba.index,
                           e, e.copy(), e.copy(), True)
-    if planner not in ("paper", "global", "slack_pool", "roofline"):
+    if planner not in ("paper", "global"):
         raise ValueError(f"unknown planner: {planner}")
-    if planner == "slack_pool":  # historical alias
-        planner = "global"
 
     slot = deadline_s / n  # Algorithm 1 line 3: equal time slots
     states = ladder.states
@@ -614,7 +589,7 @@ def plan_dvfs_arrays(
         return PlanArrays("paper", deadline_s, slot, ba.index,
                           states_arr[pos], times, energies, feasible)
 
-    # --- global greedy ("global" / "roofline") ------------------------------
+    # --- global greedy ---------------------------------------------------------
     # state: per-block ladder position (start at f_max); lower the block whose
     # next down-step has the best ΔE/Δt while total time fits
     # deadline*(1-margin).  Initial times/energies at rel_freq=1.0 exactly
@@ -626,7 +601,7 @@ def plan_dvfs_arrays(
     group_budget = np.array([deadline_s * (1.0 - error_margin)])
     _run_downclock_tables(times_tab, energies_tab, pos, times, energies,
                           np.zeros(n, dtype=np.int64), group_total,
-                          group_budget, exact=exact)
+                          group_budget)
     feasible = bool(sum(times.tolist()) <= deadline_s + 1e-9)
     return PlanArrays(planner, deadline_s, slot, ba.index,
                       states_arr[pos], times, energies, feasible)

@@ -61,7 +61,7 @@ def make_prompts(cfg, batch: int, prompt_len: int, seed: int = 0) -> dict:
 
 
 def build_engine(cfg, params, *, batch: int, max_len: int, window: int,
-                 planner: str = "roofline") -> ServingEngine:
+                 planner: str = "global") -> ServingEngine:
     """The serving engine with a decode roofline sized from ``cfg``."""
     rt = RooflineTimeModel.from_counts(
         flops=2 * cfg.param_count() * batch,
@@ -80,8 +80,8 @@ def main(argv=None):
                     help="default: the preset's")
     ap.add_argument("--tokens", type=int, default=None,
                     help="default: the preset's")
-    ap.add_argument("--planner", default="roofline",
-                    choices=["paper", "global", "roofline"])
+    ap.add_argument("--planner", default="global",
+                    choices=["paper", "global"])
     args = ap.parse_args(argv)
 
     enable_compile_cache()

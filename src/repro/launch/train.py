@@ -27,7 +27,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
     ap.add_argument("--planner", default="paper",
-                    choices=["paper", "global", "roofline"])
+                    choices=["paper", "global"])
     ap.add_argument("--no-dvfs", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()

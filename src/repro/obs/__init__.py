@@ -28,39 +28,8 @@ bitwise-identical, so every artifact here is too):
   rolls per-block deltas up to per-node/-tenant/-mechanism tables;
 * :mod:`repro.obs.watchdog` — SRE-style multi-window SLO burn-rate
   alerting off the streaming metrics, deterministic alert streams.
+
+Each name is imported from the module that defines it; this package
+imports none of them, so ``repro.core`` can import the recorder without
+loading the runtime that the simulated views read.
 """
-
-import importlib
-
-from repro.obs import tracer
-
-# the simulated views import the runtime, which imports repro.core; they
-# load on first use, so that repro.core can import the tracer
-_LAZY = {
-    "counterfactual": ("MECHANISMS", "Scenario", "ablate", "delta_ledger",
-                       "mechanism_columns", "neutralize",
-                       "profile_mechanisms"),
-    "diff": ("RunDiff", "diff_runs"),
-    "explain": ("explain_energy", "explain_miss"),
-    "export": ("to_chrome_trace", "to_jsonl", "to_prometheus",
-               "validate_chrome_trace", "validate_prometheus",
-               "write_chrome_trace", "write_jsonl"),
-    "metrics": ("StreamingMetrics", "format_table", "node_rows",
-                "tenant_rows"),
-    "spans": ("Span", "build_job_spans", "build_spans", "flatten",
-              "require_full_log"),
-    "watchdog": ("Alert", "Rule", "Watchdog", "standard_rules"),
-}
-_HOME = {name: mod for mod, names in _LAZY.items() for name in names}
-
-
-def __getattr__(name: str):
-    mod = _HOME.get(name)
-    if mod is None:
-        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-    value = getattr(importlib.import_module(f"{__name__}.{mod}"), name)
-    globals()[name] = value
-    return value
-
-
-__all__ = ["tracer", *_HOME]

@@ -1,18 +1,18 @@
-"""The chunked SoA dataset→plan path (see package docstring).
+"""The chunked SoA dataset→plan path of one node (see package docstring).
 
 ``stream_estimates`` drives the sampling stage chunk by chunk and
 accumulates ``EstimateArrays``; ``plan_estimates`` hands the accumulated SoA
-straight to the vectorized single-node or cluster planner; ``stream_plan``
-is the two glued together.  ``stream_estimates_tokens`` is the token-blocks
-front: it picks each block's sample rows by stateless hash, reduces them
-with ONE ``block_stats_batched_pallas`` dispatch per chunk (the kernel's
-ragged-row masking handles per-block sample sizes), and prices records with
-a linear model over the kernel's [nonpad, matches, mass] features.
+straight to the vectorized single-node planner.  ``stream_estimates_tokens``
+is the token-blocks front: it picks each block's sample rows by stateless
+hash, reduces them with ONE ``block_stats_batched_pallas`` dispatch per
+chunk (the kernel's ragged-row masking handles per-block sample sizes), and
+prices records with a linear model over the kernel's [nonpad, matches,
+mass] features.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, Iterator, Sequence
+from typing import Iterable, Iterator
 
 import numpy as np
 
@@ -20,12 +20,11 @@ from repro.core.energy import DEFAULT_LADDER, FrequencyLadder, PowerModel, TPU_V
 from repro.core.sampling import (_DOMAIN_SAMPLER, _hash_uniform,
                                  _z_for_confidence, sample_blocks_soa)
 from repro.core.scheduler import plan_dvfs_arrays
-from repro.core.soa import BlockArrays, EstimateArrays, PlanArrays
+from repro.core.soa import EstimateArrays, PlanArrays
 from repro.obs.tracer import count, span
 
 __all__ = ["PipelineConfig", "stream_estimates", "stream_estimates_tokens",
-           "sample_token_rows", "token_chunk_estimates", "plan_estimates", "stream_plan",
-           "stream_run"]
+           "sample_token_rows", "token_chunk_estimates", "plan_estimates"]
 
 # default linear record-cost model over the kernel's per-row features:
 # seconds ≈ w·[nonpad, matches, mass].  Values are arbitrary but fixed —
@@ -41,46 +40,13 @@ class PipelineConfig:
     # sampling stage
     fraction: float = 0.05
     min_samples: int = 16
-    n_boot: int = 200            # exact sampler only (batched CI is analytic)
     confidence: float = 0.95
     seed: int = 0
-    sampler: str = "batched"     # "batched" (hot path) | "exact" (oracle)
     # planning stage
     planner: str = "global"
     ladder: FrequencyLadder = DEFAULT_LADDER
     power: PowerModel = TPU_V5E_POWER
     error_margin: float = 0.05
-    adaptive_margin: bool = False
-    # measured calibration (repro.calibrate) — closes the estimate->plan->
-    # measure loop for STREAMED plans:
-    #   * a ``CostFit`` prices token blocks with the fitted per-record cost
-    #     instead of the linear token model, and stamps every planned block
-    #     with the fit's max-form roofline (calibrated memory-bound
-    #     fraction), exactly as ``CostFit.roofline()`` would per block;
-    #   * a ``CounterTrace`` upgrades the node specs at plan time
-    #     (``plan_cluster_arrays(calibration=trace)``);
-    #   * a ``(CostFit, CounterTrace)`` tuple applies both.
-    calibration: object = None
-
-
-def _split_calibration(config: "PipelineConfig"):
-    """-> (CostFit | None, CounterTrace | None) from the config hook."""
-    cal = config.calibration
-    if cal is None:
-        return None, None
-    from repro.calibrate.fit import CostFit
-    from repro.calibrate.trace import CounterTrace
-    if isinstance(cal, CostFit):
-        return cal, None
-    if isinstance(cal, CounterTrace):
-        return None, cal
-    if isinstance(cal, tuple) and len(cal) == 2 \
-            and isinstance(cal[0], CostFit) \
-            and isinstance(cal[1], CounterTrace):
-        return cal[0], cal[1]
-    raise TypeError("PipelineConfig.calibration must be a CostFit, a "
-                    f"CounterTrace, or a (CostFit, CounterTrace) tuple, "
-                    f"got {type(cal).__name__}")
 
 
 def _iter_chunks(source, chunk_size: int) -> Iterator[dict]:
@@ -130,9 +96,8 @@ def stream_estimates(source, config: PipelineConfig = PipelineConfig()
             costs = np.asarray(chunk["costs"], dtype=np.float64)
             est = sample_blocks_soa(
                 costs, chunk.get("lengths"), fraction=config.fraction,
-                min_samples=config.min_samples, n_boot=config.n_boot,
-                confidence=config.confidence, seed=config.seed,
-                start_index=offset, method=config.sampler)
+                min_samples=config.min_samples, confidence=config.confidence,
+                seed=config.seed, start_index=offset)
             parts.append(est)
             offset += len(est)
             count(blocks=len(est), records=int(est.n_records.sum()))
@@ -193,17 +158,6 @@ def token_chunk_estimates(
     tokens = np.asarray(tokens)
     b, r, length = tokens.shape
     index = start_index + np.arange(b, dtype=np.int64)
-    fit, _ = _split_calibration(config)
-    if fit is not None:
-        # calibrated pricing: the fitted per-record cost replaces the
-        # linear token model outright — cost is a pure function of record
-        # count, so no rows are sampled and no kernel dispatch runs; the
-        # CI halfwidth is the fit's own residual scale
-        total = fit.est_time_fmax(np.full(b, float(r)))
-        hw = _z_for_confidence(config.confidence) * fit.rmse_s
-        return EstimateArrays(index, total, total - hw, total + hw,
-                              np.zeros(b, dtype=np.int64),
-                              np.full(b, r, dtype=np.int64))
     sampled, k = sample_token_rows(tokens, start_index=start_index,
                                    config=config)
     kmax = sampled.shape[1]
@@ -270,107 +224,19 @@ def plan_estimates(
     est: EstimateArrays,
     deadline_s: float,
     config: PipelineConfig = PipelineConfig(),
-    *,
-    nodes: Sequence | None = None,
-    assignment="auto",
-    util: np.ndarray | None = None,
-    power_cap_w: float | None = None,
-):
+) -> PlanArrays:
     """Planning stage: SoA estimates straight into the vectorized planner.
 
-    Single-node by default (``PlanArrays``); passing ``nodes`` routes the
-    same ``BlockArrays`` through ``plan_cluster_arrays``
-    (``ClusterPlanArrays``), where ``power_cap_w`` adds the cluster-wide
-    Σ-power screen.  ``config.calibration`` applies here: a ``CostFit``
-    stamps every block with the fit's calibrated roofline (identical to
-    ``CostFit.roofline()`` per block), a ``CounterTrace`` calibrates the
-    node specs before the cluster plan.
+    A cluster plan belongs to the layer above: the cluster package's
+    ``plan_cluster_arrays`` over the same ``est.to_block_arrays()``.
     """
     # the record holds what a missed deadline is read from: the deadline,
     # the summed estimate and the plan's own predicted time
     with span("pipeline.plan", blocks=len(est), deadline_s=float(deadline_s),
               est_s=float(est.total.sum())):
-        fit, trace = _split_calibration(config)
-        roofline = fit.roofline_arrays(est.n_records) if fit is not None \
-            else None
-        ba = est.to_block_arrays(util=util, roofline=roofline)
-        if nodes is not None:
-            from repro.cluster.planner import plan_cluster_arrays
-            plan = plan_cluster_arrays(ba, nodes, deadline_s,
-                                       assignment=assignment,
-                                       error_margin=config.error_margin,
-                                       power_cap_w=power_cap_w,
-                                       calibration=trace)
-            count(planned_s=plan.pred_makespan_s)
-            return plan
-        if power_cap_w is not None:
-            raise ValueError("power_cap_w needs a cluster plan (pass nodes)")
-        plan = plan_dvfs_arrays(ba, deadline_s, planner=config.planner,
-                                ladder=config.ladder, power=config.power,
-                                error_margin=config.error_margin,
-                                adaptive_margin=config.adaptive_margin)
+        plan = plan_dvfs_arrays(est.to_block_arrays(), deadline_s,
+                                planner=config.planner, ladder=config.ladder,
+                                power=config.power,
+                                error_margin=config.error_margin)
         count(planned_s=plan.pred_total_time)
         return plan
-
-
-def stream_plan(
-    source,
-    deadline_s: float,
-    config: PipelineConfig = PipelineConfig(),
-    *,
-    nodes: Sequence | None = None,
-    assignment="auto",
-):
-    """End to end: chunked cost source -> ``PlanArrays``/``ClusterPlanArrays``.
-
-    The whole dataset→plan path with no per-block Python objects; blocks
-    stream through sampling in ``config.chunk_size`` chunks, and the planner
-    consumes the accumulated SoA estimates in one vectorized pass.
-    """
-    est = source if isinstance(source, EstimateArrays) \
-        else stream_estimates(source, config)
-    return plan_estimates(est, deadline_s, config, nodes=nodes,
-                          assignment=assignment)
-
-
-def stream_run(
-    source,
-    deadline_s: float,
-    config: PipelineConfig = PipelineConfig(),
-    *,
-    nodes: Sequence,
-    assignment="auto",
-    truth: BlockArrays | None = None,
-    runtime=None,
-    events=(),
-    power_cap_w: float | None = None,
-):
-    """Dataset → plan → event-driven execution, SoA end to end.
-
-    The plan→runtime handoff: the accumulated ``EstimateArrays`` become a
-    ``ClusterPlanArrays`` (``power_cap_w`` screens the plan) which feeds
-    ``repro.runtime.run_cluster`` directly — a million streamed blocks go
-    from records to a simulated cluster run without one per-block Python
-    object on the planning side.  ``truth`` defaults to the estimates
-    themselves (drift-free execution); pass the real costs to study
-    estimate error, and ``events``/``runtime`` (a ``RuntimeConfig``) to
-    inject faults, migration, actuation latency, or the runtime-side cap.
-    """
-    from repro.runtime.engine import RuntimeConfig, run_cluster
-    est = source if isinstance(source, EstimateArrays) \
-        else stream_estimates(source, config)
-    cpa = plan_estimates(est, deadline_s, config, nodes=nodes,
-                         assignment=assignment, power_cap_w=power_cap_w)
-    ba = truth if truth is not None else est.to_block_arrays()
-    # default config keeps the event log off: at the million-block scale a
-    # per-event tuple log would defeat the pipeline's bounded memory
-    if runtime is None:
-        cfg = RuntimeConfig(power_cap_w=power_cap_w, log_events=False)
-    elif power_cap_w is not None and runtime.power_cap_w is None:
-        # the cap must bind at run time too, not just screen the plan
-        cfg = dataclasses.replace(runtime, power_cap_w=power_cap_w)
-    elif power_cap_w is not None and runtime.power_cap_w != power_cap_w:
-        raise ValueError("power_cap_w disagrees with runtime.power_cap_w")
-    else:
-        cfg = runtime
-    return run_cluster(cpa, ba, config=cfg, events=events)

@@ -3,8 +3,8 @@
 Serving maps onto the paper even more directly than training: each decode window
 (a fixed number of tokens for the whole batch) is a "block", the per-request SLO
 is the deadline, and decode is memory-bandwidth-bound on TPU — exactly the regime
-where the roofline planner harvests FREE energy savings (clock down to the
-zero-cost point without breaking the SLO).
+where the planner, given the decode roofline, harvests FREE energy savings
+(clock down to the zero-cost point without breaking the SLO).
 """
 from __future__ import annotations
 
@@ -31,7 +31,7 @@ class ServeConfig:
     window: int = 16            # decode tokens per scheduling block
     slo_tokens_per_s: float = 0.0   # 0 = derive from measured f_max rate
     slack: float = 1.2          # deadline = slack * f_max time when no SLO given
-    planner: str = "roofline"
+    planner: str = "global"
     greedy: bool = True
     # multi-replica decode: N replicas each decode their own batch under the
     # shared SLO; the cluster planner picks per-replica window frequencies

@@ -1,7 +1,6 @@
 from repro.train.loop import (InjectedFailure, Trainer, TrainConfig,
                               make_train_step)
 from repro.train.dvfs_controller import DVFSController, SimulatedActuator
-from repro.train.straggler import StragglerDetector
 
 __all__ = ["Trainer", "TrainConfig", "make_train_step", "InjectedFailure",
-           "DVFSController", "SimulatedActuator", "StragglerDetector"]
+           "DVFSController", "SimulatedActuator"]

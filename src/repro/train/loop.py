@@ -26,7 +26,7 @@ from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          clip_by_global_norm, linear_warmup_cosine)
 from repro.train.dvfs_controller import (DVFSController, EnergyLedger,
                                          SimulatedActuator)
-from repro.train.straggler import StragglerDetector
+from repro.cluster.straggler import StragglerDetector
 
 __all__ = ["TrainConfig", "make_train_step", "Trainer", "InjectedFailure",
            "CALIBRATION_STEPS"]

@@ -9,12 +9,13 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager, load_checkpoint, save_checkpoint
+from repro.cluster.straggler import StragglerDetector
 from repro.configs import smoke_config
 from repro.data import BlockDataset
 from repro.models import transformer as T
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          clip_by_global_norm, linear_warmup_cosine)
-from repro.train import StragglerDetector, TrainConfig, Trainer
+from repro.train import TrainConfig, Trainer
 from repro.train.loop import CALIBRATION_STEPS
 
 

@@ -36,7 +36,12 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from test_runtime_vector import _everything_on_parts, _scenario
 
-from repro import obs
+from repro.obs.explain import explain_energy, explain_miss
+from repro.obs.export import (to_chrome_trace, to_prometheus,
+                              validate_chrome_trace, write_chrome_trace,
+                              write_jsonl)
+from repro.obs.metrics import StreamingMetrics, format_table, node_rows
+from repro.obs.spans import build_job_spans, build_spans, flatten
 from repro.runtime import (NodeFailureEvent, RecoveryPolicy, RuntimeConfig,
                            run_cluster)
 from repro.runtime.events import EventLogSink
@@ -70,8 +75,8 @@ def _run(parts, engine, **cfg_kw):
 @given(st.integers(min_value=0, max_value=10**6))
 def test_span_forests_identical_scalar_vector(seed):
     parts = _scenario(seed)
-    a = obs.build_spans(_run(parts, "scalar").event_log)
-    b = obs.build_spans(_run(parts, "vector").event_log)
+    a = build_spans(_run(parts, "scalar").event_log)
+    b = build_spans(_run(parts, "vector").event_log)
     assert a == b
 
 
@@ -79,13 +84,13 @@ def test_everything_on_spans_cover_lifecycle():
     parts = _crash_parts()
     rep_a = _run(parts, "scalar")
     rep_b = _run(parts, "vector")
-    sa = obs.build_spans(rep_a.event_log)
-    assert sa == obs.build_spans(rep_b.event_log)
-    cats = {s.cat for s in obs.flatten(sa)}
+    sa = build_spans(rep_a.event_log)
+    assert sa == build_spans(rep_b.event_log)
+    cats = {s.cat for s in flatten(sa)}
     assert {"block", "freq", "telemetry", "wire", "migrate_in",
             "migrate_out", "crashed", "outage"} <= cats
     # block spans tile their busy time: children cover [start, end]
-    for s in obs.flatten(sa):
+    for s in flatten(sa):
         if s.cat == "block":
             segs = [c for c in s.children if c.cat == "freq"]
             assert segs and segs[0].start == s.start \
@@ -93,7 +98,7 @@ def test_everything_on_spans_cover_lifecycle():
             for c in s.children:
                 assert s.start <= c.start <= c.end <= s.end
     # one outage per crash; the repaired one carries its down_s
-    outages = [s for s in obs.flatten(sa) if s.cat == "outage"]
+    outages = [s for s in flatten(sa) if s.cat == "outage"]
     assert len(outages) == rep_a.n_crashes
     repaired = [s for s in outages if s.get("down_s") is not None]
     assert repaired and repaired[0].dur == pytest.approx(
@@ -108,8 +113,8 @@ def test_job_spans_identical_and_well_formed():
                            config=sc.config(), serving=sc.serving,
                            events=sc.events, est_blocks=sc.blocks,
                            engine=engine)
-        spans = obs.build_spans(srep.event_log)
-        jspans = obs.build_job_spans(srep, spans)
+        spans = build_spans(srep.event_log)
+        jspans = build_job_spans(srep, spans)
         got.append((spans, jspans))
         assert len(jspans) == len(srep.jobs)
         for js, jr in zip(jspans, srep.jobs):
@@ -129,7 +134,7 @@ def test_build_spans_rejects_ring_artifact():
                  (1.0, "block_finish", "n0", 0, 1.0, 50.0),
                  (2.0, "block_start", "n0", 1, 1.0)])
     with pytest.raises(ValueError, match="ring"):
-        obs.build_spans(sink)
+        build_spans(sink)
 
 
 # ----------------------------------------------------------- (b) attribution
@@ -137,10 +142,10 @@ def test_build_spans_rejects_ring_artifact():
 def test_explain_miss_sums_exactly_per_node():
     parts = _crash_parts()
     rep = _run(parts, "vector")
-    spans = obs.build_spans(rep.event_log)
+    spans = build_spans(rep.event_log)
     crash_seen = 0.0
     for nr in rep.node_reports:
-        ex = obs.explain_miss(rep, node=nr.name, spans=spans)
+        ex = explain_miss(rep, node=nr.name, spans=spans)
         assert math.fsum([ex[k] for k in MISS_KEYS]) == ex["wall_s"]
         assert ex["wall_s"] == nr.finish_s
         assert all(ex[k] >= 0.0 for k in MISS_KEYS if k != "service_s")
@@ -153,9 +158,9 @@ def test_explain_miss_sums_exactly_per_node():
 def test_explain_miss_exact_on_random_scenarios(seed):
     parts = _scenario(seed)
     rep = _run(parts, "vector")
-    spans = obs.build_spans(rep.event_log)
+    spans = build_spans(rep.event_log)
     for nr in rep.node_reports:
-        ex = obs.explain_miss(rep, node=nr.name, spans=spans)
+        ex = explain_miss(rep, node=nr.name, spans=spans)
         assert math.fsum([ex[k] for k in MISS_KEYS]) == ex["wall_s"]
 
 
@@ -166,9 +171,9 @@ def test_explain_miss_sums_exactly_per_job():
                            config=sc.config(), serving=sc.serving,
                            events=sc.events, est_blocks=sc.blocks,
                            engine="vector")
-        spans = obs.build_spans(srep.event_log)
+        spans = build_spans(srep.event_log)
         for jr in srep.jobs:
-            ex = obs.explain_miss(srep, job_id=jr.job_id, spans=spans)
+            ex = explain_miss(srep, job_id=jr.job_id, spans=spans)
             assert math.fsum([ex[k] for k in MISS_KEYS]) == ex["wall_s"]
             assert ex["missed"] == (not jr.slo_met)
             if jr.status == "rejected":
@@ -178,20 +183,20 @@ def test_explain_miss_sums_exactly_per_job():
 def test_explain_miss_argument_validation():
     rep = _run(_scenario(3), "vector")
     with pytest.raises(ValueError):
-        obs.explain_miss(rep)
+        explain_miss(rep)
     with pytest.raises(ValueError):
-        obs.explain_miss(rep, job_id=0, node="n0")
+        explain_miss(rep, job_id=0, node="n0")
     with pytest.raises(KeyError):
-        obs.explain_miss(rep, node="nope")
+        explain_miss(rep, node="nope")
     with pytest.raises(TypeError):
-        obs.explain_miss(rep, job_id=0)  # not a ServingReport
+        explain_miss(rep, job_id=0)  # not a ServingReport
 
 
 def test_explain_energy_channels_sum_exactly():
     parts = _crash_parts()
     plan = parts[0]
     rep = _run(parts, "vector")
-    ee = obs.explain_energy(rep)
+    ee = explain_energy(rep)
     assert math.fsum([ee["busy_j"], ee["idle_j"], ee["switch_j"],
                       ee["wire_j"], ee["failed_j"]]) == ee["total_j"]
     assert ee["busy_j"] == rep.total_energy_j
@@ -199,7 +204,7 @@ def test_explain_energy_channels_sum_exactly():
     assert ee["failed_j"] == rep.failed_energy_j
     # per-node idles reproduce the engine's own formula and sum order
     specs = [npa.node for npa in plan.node_plans]
-    per_node = [obs.explain_energy(rep, node=s.name, specs=specs)
+    per_node = [explain_energy(rep, node=s.name, specs=specs)
                 for s in specs]
     assert sum(e["idle_j"] for e in per_node) == rep.idle_energy_j
     assert sum(e["busy_j"] for e in per_node) == rep.total_energy_j
@@ -208,7 +213,7 @@ def test_explain_energy_channels_sum_exactly():
 # -------------------------------------------------------------- (c) metrics
 
 def _metrics_run(parts, engine):
-    mx = obs.StreamingMetrics()
+    mx = StreamingMetrics()
     rep = _run(parts, engine, metrics=mx)
     return mx, rep
 
@@ -254,8 +259,8 @@ def test_metrics_power_track_integrates_to_ledger(engine):
 
 def test_metrics_horizon_growth_preserves_integrals():
     parts = _everything_on_parts()
-    small = obs.StreamingMetrics(bins=64, horizon_s=1e-3)  # forces rebins
-    big = obs.StreamingMetrics(bins=64)
+    small = StreamingMetrics(bins=64, horizon_s=1e-3)  # forces rebins
+    big = StreamingMetrics(bins=64)
     rep_s = _run(parts, "vector", metrics=small)
     rep_b = _run(parts, "vector", metrics=big)
     assert rep_s == rep_b
@@ -268,7 +273,7 @@ def test_metrics_horizon_growth_preserves_integrals():
 
 def test_metrics_work_without_event_log():
     parts = _everything_on_parts()
-    mx = obs.StreamingMetrics()
+    mx = StreamingMetrics()
     rep = _run(parts, "vector", metrics=mx, event_log="off")
     assert rep.event_log == () and rep.power_samples == ()
     assert mx.snapshot()["counters"]["finishes"] == \
@@ -278,19 +283,19 @@ def test_metrics_work_without_event_log():
 
 
 def test_metrics_single_use_and_binding_guards():
-    mx = obs.StreamingMetrics()
+    mx = StreamingMetrics()
     with pytest.raises(RuntimeError, match="not bound"):
         mx.snapshot()
     _run(_scenario(3), "vector", metrics=mx)
     with pytest.raises(RuntimeError, match="exactly one run"):
         _run(_scenario(3), "vector", metrics=mx)
     with pytest.raises(ValueError):
-        obs.StreamingMetrics(bins=7)
+        StreamingMetrics(bins=7)
 
 
 def test_serving_metrics_count_decisions():
     sc = serving_scenario(5)
-    mx = obs.StreamingMetrics()
+    mx = StreamingMetrics()
     srep = run_serving(sc.plan, sc.truth, sc.arrivals,
                        config=dataclasses.replace(sc.config(), metrics=mx),
                        serving=sc.serving, events=sc.events,
@@ -378,8 +383,8 @@ def test_serving_requires_full_event_log():
 def test_chrome_trace_validates_and_has_tracks():
     parts = _crash_parts()
     rep = _run(parts, "vector")
-    doc = obs.to_chrome_trace(rep)
-    assert obs.validate_chrome_trace(doc) == []
+    doc = to_chrome_trace(rep)
+    assert validate_chrome_trace(doc) == []
     ev = doc["traceEvents"]
     names = {e["args"]["name"] for e in ev if e["ph"] == "M"}
     assert "cluster" in names and any(n.startswith("node:") for n in names)
@@ -397,18 +402,18 @@ def test_chrome_trace_serving_jobs_track(tmp_path):
                        serving=sc.serving, events=sc.events,
                        est_blocks=sc.blocks, engine="vector")
     path = tmp_path / "trace.json"
-    doc = obs.write_chrome_trace(path, srep)
-    assert obs.validate_chrome_trace(doc) == []
+    doc = write_chrome_trace(path, srep)
+    assert validate_chrome_trace(doc) == []
     on_disk = json.loads(path.read_text())
-    assert obs.validate_chrome_trace(on_disk) == []
+    assert validate_chrome_trace(on_disk) == []
     names = {e["args"]["name"] for e in on_disk["traceEvents"]
              if e["ph"] == "M"}
     assert "jobs" in names
 
 
 def test_chrome_trace_validator_rejects_malformed():
-    assert obs.validate_chrome_trace([]) != []
-    assert obs.validate_chrome_trace({"traceEvents": {}}) != []
+    assert validate_chrome_trace([]) != []
+    assert validate_chrome_trace({"traceEvents": {}}) != []
     cases = [
         {"ph": "Q", "name": "x", "pid": 0, "ts": 0.0},
         {"ph": "X", "name": "x", "pid": 0, "ts": -1.0, "dur": 1.0},
@@ -418,14 +423,14 @@ def test_chrome_trace_validator_rejects_malformed():
         {"ph": "X", "name": "x", "pid": "zero", "ts": 0.0, "dur": 1.0},
     ]
     for ev in cases:
-        assert obs.validate_chrome_trace({"traceEvents": [ev]}) != [], ev
+        assert validate_chrome_trace({"traceEvents": [ev]}) != [], ev
 
 
 def test_prometheus_exposition_well_formed():
     parts = _everything_on_parts()
-    mx = obs.StreamingMetrics()
+    mx = StreamingMetrics()
     rep = _run(parts, "vector", metrics=mx)
-    for text in (obs.to_prometheus(mx), obs.to_prometheus(rep)):
+    for text in (to_prometheus(mx), to_prometheus(rep)):
         assert text.endswith("\n")
         for line in text.splitlines():
             if line.startswith("#"):
@@ -434,14 +439,14 @@ def test_prometheus_exposition_well_formed():
                 name_part, value = line.rsplit(" ", 1)
                 float(value)  # must parse
                 assert name_part.startswith("repro_")
-    assert 'node="n0"' in obs.to_prometheus(mx)
-    assert "repro_energy_joules" in obs.to_prometheus(rep)
+    assert 'node="n0"' in to_prometheus(mx)
+    assert "repro_energy_joules" in to_prometheus(rep)
 
 
 def test_jsonl_round_trips_event_log(tmp_path):
     rep = _run(_everything_on_parts(), "vector")
     path = tmp_path / "events.jsonl"
-    n = obs.write_jsonl(path, rep.event_log)
+    n = write_jsonl(path, rep.event_log)
     assert n == len(rep.event_log)
     rows = [json.loads(line) for line in path.read_text().splitlines()]
     assert len(rows) == n
@@ -451,13 +456,13 @@ def test_jsonl_round_trips_event_log(tmp_path):
 
 def test_node_rows_and_format_table():
     rep = _run(_crash_parts(), "vector")
-    rows = obs.node_rows(rep)
+    rows = node_rows(rep)
     assert [r["node"] for r in rows] == [nr.name for nr in rep.node_reports]
     assert any(r["state"] == "DOWN" for r in rows)  # the permanent crash
-    text = obs.format_table(rows, [("node", "node", "s"),
-                                   ("blocks", "blocks", "d"),
-                                   ("busy_s", "busy", "9.2f"),
-                                   ("state", "state", "s")])
+    text = format_table(rows, [("node", "node", "s"),
+                               ("blocks", "blocks", "d"),
+                               ("busy_s", "busy", "9.2f"),
+                               ("state", "state", "s")])
     lines = text.splitlines()
     assert len(lines) == len(rows) + 1
     assert len({len(ln) for ln in lines}) == 1  # aligned

@@ -30,8 +30,15 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from test_runtime_vector import _everything_on_parts, _scenario
 
-from repro import obs
 from repro.cluster.controller import OnlineReplanner
+from repro.obs.counterfactual import (Scenario, ablate, neutralize,
+                                      profile_mechanisms)
+from repro.obs.diff import diff_runs
+from repro.obs.explain import explain_energy, explain_miss
+from repro.obs.export import to_prometheus, validate_prometheus
+from repro.obs.metrics import StreamingMetrics
+from repro.obs.spans import build_spans, require_full_log
+from repro.obs.watchdog import Alert, Rule, Watchdog, standard_rules
 from repro.serving import run_serving, serving_scenario
 
 CHANNELS = ("busy_j", "idle_j", "switch_j", "wire_j", "failed_j")
@@ -39,8 +46,8 @@ CHANNELS = ("busy_j", "idle_j", "switch_j", "wire_j", "failed_j")
 
 def _cf_scenario(seed=None, parts=None):
     plan, truth, cfg, events, blocks = parts if parts else _scenario(seed)
-    return obs.Scenario(plan=plan, truth=truth, config=cfg,
-                        events=tuple(events), est_blocks=blocks)
+    return Scenario(plan=plan, truth=truth, config=cfg,
+                    events=tuple(events), est_blocks=blocks)
 
 
 def _assert_reconciled(row):
@@ -54,7 +61,7 @@ def _assert_reconciled(row):
 @given(st.integers(min_value=0, max_value=10**6))
 def test_delta_ledger_reconciles_exactly(seed):
     sc = _cf_scenario(seed)
-    for row in obs.profile_mechanisms(sc, engines=("vector",)):
+    for row in profile_mechanisms(sc, engines=("vector",)):
         _assert_reconciled(row)
         if not row["changed"]:   # identity replay: every delta exactly zero
             assert row["d_total_j"] == 0.0
@@ -64,7 +71,7 @@ def test_delta_ledger_reconciles_exactly(seed):
 
 def test_everything_on_both_engines_and_paper_headline():
     sc = _cf_scenario(parts=_everything_on_parts(seed=7))
-    rows = obs.profile_mechanisms(sc, engines=("vector", "scalar"))
+    rows = profile_mechanisms(sc, engines=("vector", "scalar"))
     for row in rows:
         _assert_reconciled(row)
     dvfs = next(r for r in rows if r["mechanism"] == "dvfs")
@@ -76,26 +83,26 @@ def test_everything_on_both_engines_and_paper_headline():
 
 def test_neutralize_dvfs_pins_every_ladder():
     sc = _cf_scenario(parts=_everything_on_parts(seed=7))
-    neutral, changed = obs.neutralize(sc, "dvfs")
+    neutral, changed = neutralize(sc, "dvfs")
     assert changed
     cpa = neutral.plan.to_arrays()
     assert all(npa.node.ladder.states == (1.0,) for npa in cpa.node_plans)
     # neutralizing the already-pinned scenario is a no-op
-    again, changed2 = obs.neutralize(neutral, "dvfs")
+    again, changed2 = neutralize(neutral, "dvfs")
     assert not changed2 and again is neutral
 
 
 def test_neutralize_rejects_unknown_mechanism():
     sc = _cf_scenario(seed=3)
     with pytest.raises(ValueError, match="unknown mechanism"):
-        obs.neutralize(sc, "gremlins")
+        neutralize(sc, "gremlins")
 
 
 def test_scenario_rejects_stateful_config():
     plan, truth, cfg, events, blocks = _scenario(3)
-    bad = dataclasses.replace(cfg, metrics=obs.StreamingMetrics())
+    bad = dataclasses.replace(cfg, metrics=StreamingMetrics())
     with pytest.raises(ValueError, match="metrics"):
-        obs.Scenario(plan=plan, truth=truth, config=bad)
+        Scenario(plan=plan, truth=truth, config=bad)
 
 
 # ------------------------------------------------------------- (b) run-diff
@@ -104,7 +111,7 @@ def test_diff_identity_is_empty():
     sc = _cf_scenario(parts=_everything_on_parts(seed=7))
     a = sc.run(engine="vector")
     b = sc.run(engine="vector")
-    d = obs.diff_runs(a, b)
+    d = diff_runs(a, b)
     assert d.empty
     assert d.spans_aligned
 
@@ -112,13 +119,13 @@ def test_diff_identity_is_empty():
 def test_diff_attributes_migration_ablation():
     sc = _cf_scenario(parts=_everything_on_parts(seed=7))
     base = sc.run(engine="vector")
-    abl = obs.ablate(sc, "migration", engines=("vector",))
-    d = obs.diff_runs(base, abl)
+    abl = ablate(sc, "migration", engines=("vector",))
+    d = diff_runs(base, abl)
     assert not d.empty
     assert d.blocks or d.moved
     assert any(m["mechanism"] == "migration" for m in d.mechanisms)
     # swapped arguments negate the totals and swap the move endpoints
-    r = obs.diff_runs(abl, base)
+    r = diff_runs(abl, base)
     assert r.totals["d_total_j"] == -d.totals["d_total_j"]
     assert sorted((i, b, a) for i, a, b in d.moved) == sorted(r.moved)
 
@@ -140,14 +147,14 @@ def _shedding_serving_scenario():
 
 def test_diff_add_drop_round_trip_under_shedding():
     ss = _shedding_serving_scenario()
-    sc = obs.Scenario(plan=ss.plan, truth=ss.truth, config=ss.config(),
-                      events=tuple(ss.events), est_blocks=ss.blocks,
-                      arrivals=ss.arrivals, serving=ss.serving,
-                      arrival_truth=ss.arrival_truth)
+    sc = Scenario(plan=ss.plan, truth=ss.truth, config=ss.config(),
+                  events=tuple(ss.events), est_blocks=ss.blocks,
+                  arrivals=ss.arrivals, serving=ss.serving,
+                  arrival_truth=ss.arrival_truth)
     assert sc.is_serving
     guarded = sc.run(engine="vector")
-    opened = obs.ablate(sc, "admission", engines=("vector",))
-    d = obs.diff_runs(guarded, opened)
+    opened = ablate(sc, "admission", engines=("vector",))
+    d = diff_runs(guarded, opened)
     # accept-all executes block work the guarded run shed or rejected
     assert d.added
     assert not d.empty
@@ -155,19 +162,19 @@ def test_diff_add_drop_round_trip_under_shedding():
     assert d.jobs and not (d.jobs_added or d.jobs_dropped)
     assert d.tenants
     # round-trip: swapping the arguments swaps added and dropped exactly
-    r = obs.diff_runs(opened, guarded)
+    r = diff_runs(opened, guarded)
     assert r.dropped == d.added
     assert r.added == d.dropped
 
 
 def test_profile_mechanisms_serving_tenant_deltas():
     ss = _shedding_serving_scenario()
-    sc = obs.Scenario(plan=ss.plan, truth=ss.truth, config=ss.config(),
-                      events=tuple(ss.events), est_blocks=ss.blocks,
-                      arrivals=ss.arrivals, serving=ss.serving,
-                      arrival_truth=ss.arrival_truth)
-    rows = obs.profile_mechanisms(sc, mechanisms=["admission"],
-                                  engines=("vector",))
+    sc = Scenario(plan=ss.plan, truth=ss.truth, config=ss.config(),
+                  events=tuple(ss.events), est_blocks=ss.blocks,
+                  arrivals=ss.arrivals, serving=ss.serving,
+                  arrival_truth=ss.arrival_truth)
+    rows = profile_mechanisms(sc, mechanisms=["admission"],
+                              engines=("vector",))
     (row,) = rows
     _assert_reconciled(row)
     assert row["changed"]
@@ -178,8 +185,8 @@ def test_profile_mechanisms_serving_tenant_deltas():
 
 def _watch(parts, engine):
     plan, truth, cfg, events, blocks = parts
-    mx = obs.StreamingMetrics()
-    wd = obs.Watchdog(obs.standard_rules(
+    mx = StreamingMetrics()
+    wd = Watchdog(standard_rules(
         plan.deadline_s, energy_budget_j=30_000.0,
         shed_budget_hz=0.5)).attach(mx)
     from repro.runtime import run_cluster
@@ -200,9 +207,9 @@ def test_watchdog_bitwise_identical_across_engines_and_runs():
 
 def test_watchdog_rule_validation():
     with pytest.raises(ValueError, match="unknown signal"):
-        obs.Rule("bad", "vibes", 1.0, 5.0)
+        Rule("bad", "vibes", 1.0, 5.0)
     with pytest.raises(ValueError, match="fast_s"):
-        obs.Rule("bad", "deadline_risk", 5.0, 1.0)
+        Rule("bad", "deadline_risk", 5.0, 1.0)
 
 
 def test_watchdog_dispatch_and_replanner_hook():
@@ -215,9 +222,9 @@ def test_watchdog_dispatch_and_replanner_hook():
 
     parts = _everything_on_parts(seed=7)
     plan, truth, cfg, events, blocks = parts
-    mx = obs.StreamingMetrics()
-    wd = obs.Watchdog(obs.standard_rules(plan.deadline_s),
-                      on_fire=fired.append, replanner=_Stub()).attach(mx)
+    mx = StreamingMetrics()
+    wd = Watchdog(standard_rules(plan.deadline_s),
+                  on_fire=fired.append, replanner=_Stub()).attach(mx)
     from repro.runtime import run_cluster
     run_cluster(plan, truth, config=dataclasses.replace(cfg, metrics=mx),
                 events=events, est_blocks=blocks, engine="vector")
@@ -233,9 +240,9 @@ def test_watchdog_dispatch_and_replanner_hook():
 def test_online_replanner_on_alert():
     plan, truth, cfg, events, blocks = _everything_on_parts(seed=7)
     ctl = OnlineReplanner(plan, est_blocks=blocks)
-    risk = obs.Alert(time=1.0, rule="deadline-risk", signal="deadline_risk",
-                     window_s=1.0, severity="page", value=2.0,
-                     slow_value=2.0)
+    risk = Alert(time=1.0, rule="deadline-risk", signal="deadline_risk",
+                 window_s=1.0, severity="page", value=2.0,
+                 slow_value=2.0)
     n = ctl.on_alert(risk)
     assert isinstance(n, int) and n >= 0
     # non-risk signals are ignored outright
@@ -253,15 +260,15 @@ def test_replay_tools_refuse_truncated_logs(mode):
     rep = run_cluster(plan, truth, config=cfg, events=events,
                       est_blocks=blocks, engine="vector")
     assert rep.event_log_mode == mode
-    for tool in (obs.build_spans,
-                 lambda r: obs.explain_miss(r, node="n0"),
-                 obs.explain_energy):
+    for tool in (build_spans,
+                 lambda r: explain_miss(r, node="n0"),
+                 explain_energy):
         with pytest.raises(ValueError) as err:
             tool(rep)
         assert mode in str(err.value)
         assert "events_dropped" in str(err.value)
     # diff_runs degrades to report-level rollups instead of raising
-    d = obs.diff_runs(rep, rep)
+    d = diff_runs(rep, rep)
     assert d.empty
     assert not d.spans_aligned
 
@@ -272,20 +279,20 @@ def test_full_log_report_still_replays():
     rep = run_cluster(plan, truth, config=cfg, events=events,
                       est_blocks=blocks, engine="vector")
     assert rep.event_log_mode == "full"
-    obs.require_full_log(rep)        # no raise
-    assert obs.build_spans(rep)      # report accepted directly
+    require_full_log(rep)        # no raise
+    assert build_spans(rep)      # report accepted directly
 
 
 # ----------------------------------------------- (e) prometheus validation
 
 def test_validate_prometheus_accepts_real_exposition():
     plan, truth, cfg, events, blocks = _everything_on_parts(seed=7)
-    mx = obs.StreamingMetrics()
+    mx = StreamingMetrics()
     from repro.runtime import run_cluster
     run_cluster(plan, truth, config=dataclasses.replace(cfg, metrics=mx),
                 events=events, est_blocks=blocks, engine="vector")
-    text = obs.to_prometheus(mx)
-    assert obs.validate_prometheus(text) == []
+    text = to_prometheus(mx)
+    assert validate_prometheus(text) == []
 
 
 GOOD = ("# HELP repro_x Stuff.\n"
@@ -305,7 +312,7 @@ GOOD = ("# HELP repro_x Stuff.\n"
     (GOOD.replace(" 1.0", " banana"), "value"),           # unparsable value
 ])
 def test_validate_prometheus_rejects(text, needle):
-    problems = obs.validate_prometheus(text)
+    problems = validate_prometheus(text)
     assert problems
     assert any(needle.lower() in p.lower() for p in problems), problems
 

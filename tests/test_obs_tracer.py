@@ -261,7 +261,7 @@ def test_key_bound_counts_candidates_and_refills(sigmas, refills,
 
 def test_importing_the_core_loads_neither_jax_nor_the_runtime():
     """The recorder imports JAX on its first span, not with ``repro.core``;
-    ``repro.obs`` loads its simulated views on first use."""
+    ``repro.obs`` loads none of its simulated views."""
     import os
     import subprocess
     import sys

@@ -5,21 +5,24 @@ per-block Python objects; this suite is the contract that its plans are
 nonetheless IDENTICAL to the object path (``BlockEstimate`` → ``BlockInfo``
 → ``plan_dvfs`` / ``plan_cluster``) run on the same estimates — across
 random chunk sizes (including boundaries that split a node's block set),
-planners, deadline regimes, and cluster assignments — and that with
-``sampler="exact"`` the estimates themselves are bit-identical to
-``sample_blocks``.  Runs under the hypothesis compat shim.
+planners, deadline regimes, and cluster assignments — and that the exact
+sampler, chunk by chunk, is bit-identical to ``sample_blocks``.  The
+pipeline plans one node; the cluster cases compose its estimates with
+``plan_cluster_arrays`` as every caller does.  Runs under the hypothesis
+compat shim.
 """
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core import (BlockArrays, BlockInfo, FrequencyLadder, PowerModel,
-                        plan_dvfs, plan_dvo, plan_dvo_arrays, sample_blocks)
+from repro.core import (BlockArrays, BlockInfo, EstimateArrays,
+                        FrequencyLadder, PowerModel, plan_dvfs, plan_dvo,
+                        plan_dvo_arrays, sample_blocks)
 from repro.core import _reference as ref
+from repro.core.sampling import sample_blocks_soa
 from repro.cluster import NodeSpec, plan_cluster, plan_cluster_arrays
 from repro.pipeline import (PipelineConfig, plan_estimates, stream_estimates,
-                            stream_estimates_tokens, stream_plan,
-                            synthetic_cost_chunks)
+                            stream_estimates_tokens, synthetic_cost_chunks)
 
 
 def _assert_plan_arrays_match_schedule(pa, plan):
@@ -48,7 +51,7 @@ def test_stream_plan_matches_object_path(n, chunk, planner, slack, z, seed):
     src = synthetic_cost_chunks(n, 24, z=z, seed=seed, chunk_size=chunk)
     est = stream_estimates(src, cfg)
     deadline = float(est.total.sum()) * (1.0 + slack) + 1e-6
-    pa = stream_plan(est, deadline, cfg)
+    pa = plan_estimates(est, deadline, cfg)
     blocks = est.to_block_arrays().to_blocks()
     _assert_plan_arrays_match_schedule(pa, plan_dvfs(blocks, deadline,
                                                      planner=planner))
@@ -79,8 +82,8 @@ def test_estimates_and_plans_invariant_to_chunk_size(n, chunk_a, chunk_b,
     assert np.array_equal(ea.ci_low, eb.ci_low)
     assert np.array_equal(ea.ci_high, eb.ci_high)
     deadline = float(ea.total.sum()) * 1.2
-    pa = stream_plan(ea, deadline, PipelineConfig())
-    pb = stream_plan(eb, deadline, PipelineConfig())
+    pa = plan_estimates(ea, deadline, PipelineConfig())
+    pb = plan_estimates(eb, deadline, PipelineConfig())
     assert np.array_equal(pa.rel_freq, pb.rel_freq)
     assert np.array_equal(pa.pred_energy_j, pb.pred_energy_j)
 
@@ -109,8 +112,8 @@ def test_stream_cluster_matches_object_path(n, chunk, n_nodes, assignment,
         synthetic_cost_chunks(n, 16, seed=seed, chunk_size=chunk), cfg)
     worst = float(est.total.sum()) / min(nd.speed for nd in nodes)
     deadline = worst * (1.0 + slack) + 1e-6
-    cpa = plan_estimates(est, deadline, cfg, nodes=nodes,
-                         assignment=assignment)
+    cpa = plan_cluster_arrays(est.to_block_arrays(), nodes, deadline,
+                              assignment=assignment)
     blocks = est.to_block_arrays().to_blocks()
     obj = plan_cluster(blocks, nodes, deadline, assignment=assignment)
     got = cpa.to_cluster_plan()
@@ -134,11 +137,14 @@ def test_stream_cluster_matches_object_path(n, chunk, n_nodes, assignment,
     seed=st.integers(0, 30),
 )
 def test_exact_sampler_bit_identical_to_sample_blocks(n, chunk, seed):
-    """sampler="exact": the SoA estimates ARE sample_blocks', bit for bit."""
+    """method="exact", chunk by chunk: the SoA estimates ARE
+    sample_blocks', bit for bit."""
     rng = np.random.default_rng(seed)
     costs = rng.lognormal(0.0, 0.6, (n, 120))
-    cfg = PipelineConfig(chunk_size=chunk, sampler="exact", seed=seed)
-    est = stream_estimates(costs, cfg)
+    est = EstimateArrays.concat([
+        sample_blocks_soa(costs[start:start + chunk], seed=seed,
+                          start_index=start, method="exact")
+        for start in range(0, n, chunk)])
     want = sample_blocks(list(costs), seed=seed)
     assert est.to_block_estimates() == want
 
@@ -212,7 +218,7 @@ def test_token_pipeline_chunk_invariant_and_planable():
     assert np.array_equal(e1.total, e2.total)
     assert np.isfinite(e1.total).all()
     assert np.all(e1.ci_high >= e1.total) and np.all(e1.ci_low <= e1.total)
-    pa = stream_plan(e1, float(e1.total.sum()) * 1.3, PipelineConfig())
+    pa = plan_estimates(e1, float(e1.total.sum()) * 1.3, PipelineConfig())
     assert pa.feasible
     assert len(pa) == 10
 
@@ -247,7 +253,7 @@ def test_plan_arrays_is_soa_not_objects():
     """The streamed plan holds arrays; BlockPlan objects only on demand."""
     est = stream_estimates(synthetic_cost_chunks(128, 16, seed=0),
                            PipelineConfig())
-    pa = stream_plan(est, float(est.total.sum()) * 1.4, PipelineConfig())
+    pa = plan_estimates(est, float(est.total.sum()) * 1.4, PipelineConfig())
     assert isinstance(pa.rel_freq, np.ndarray)
     assert isinstance(pa.pred_energy_j, np.ndarray)
     blocks = pa.to_blocks()
@@ -280,9 +286,10 @@ def _cluster_plans_equal(cpa, obj):
 )
 def test_stream_calibrated_rooflines_match_object_path(n, chunk, beta,
                                                        slack, seed):
-    """``PipelineConfig(calibration=CostFit)`` == object path with
-    ``CostFit.roofline()`` stamped block by block — the fitted memory-bound
-    fraction reaches streamed plans exactly as it reaches object plans."""
+    """The fit's rooflines stamped on the streamed SoA
+    (``CostFit.roofline_arrays``) == object path with ``CostFit.roofline()``
+    stamped block by block — the fitted memory-bound fraction reaches
+    streamed plans exactly as it reaches object plans."""
     import dataclasses as dc
     from repro.calibrate import fit_cost_model
 
@@ -295,11 +302,12 @@ def test_stream_calibrated_rooflines_match_object_path(n, chunk, beta,
     cf = fit_cost_model(rec, f, wall)
 
     nodes = [NodeSpec("a", speed=1.0), NodeSpec("b", speed=0.8)]
-    cfg = PipelineConfig(chunk_size=chunk, calibration=cf)
+    cfg = PipelineConfig(chunk_size=chunk)
     est = stream_estimates(
         synthetic_cost_chunks(n, 16, seed=seed, chunk_size=chunk), cfg)
     deadline = float(est.total.sum()) / 0.8 * (1.0 + slack) + 1e-6
-    cpa = plan_estimates(est, deadline, cfg, nodes=nodes)
+    ba = est.to_block_arrays(roofline=cf.roofline_arrays(est.n_records))
+    cpa = plan_cluster_arrays(ba, nodes, deadline)
 
     # independent object path: scalar CostFit.roofline() per block
     blocks = [dc.replace(b, roofline=cf.roofline(b.records))
@@ -309,9 +317,9 @@ def test_stream_calibrated_rooflines_match_object_path(n, chunk, beta,
 
 
 def test_stream_calibration_trace_calibrates_nodes():
-    """``PipelineConfig(calibration=CounterTrace)`` == planning against
-    ``calibrate_nodes(nodes, trace)`` — the streamed entry to the
-    estimate->plan->measure loop."""
+    """``plan_cluster_arrays(calibration=CounterTrace)`` over streamed
+    estimates == planning against ``calibrate_nodes(nodes, trace)`` — the
+    streamed entry to the estimate->plan->measure loop."""
     from repro.calibrate import calibrate_nodes, synthetic_trace
 
     tr_parts = [synthetic_trace(nm, PowerModel(), speed=s, n_samples=60,
@@ -324,38 +332,8 @@ def test_stream_calibration_trace_calibrates_nodes():
     est = stream_estimates(synthetic_cost_chunks(40, 16, seed=3),
                            PipelineConfig())
     deadline = float(est.total.sum()) * 1.2
-    cfg = PipelineConfig(calibration=tr)
-    cpa = plan_estimates(est, deadline, cfg, nodes=nodes)
+    cpa = plan_cluster_arrays(est.to_block_arrays(), nodes, deadline,
+                              calibration=tr)
     obj = plan_cluster(est.to_block_arrays().to_blocks(),
                        calibrate_nodes(nodes, tr), deadline)
     _cluster_plans_equal(cpa, obj)
-
-
-def test_token_estimates_calibrated_pricing():
-    """A CostFit replaces the linear token model: totals are
-    records * cost_per_record, nothing is sampled, and the chunked plan
-    carries the fit's roofline shape."""
-    from repro.calibrate.fit import CostFit
-
-    cf = CostFit(cost_per_record=2e-4, mem_fraction=0.4, rmse_s=1e-3,
-                 n_samples=24)
-    rng = np.random.default_rng(0)
-    toks = rng.integers(0, 50, (6, 32, 8)).astype(np.int32)
-    cfg = PipelineConfig(calibration=cf)
-    est = stream_estimates_tokens([(0, toks)], cfg)
-    assert np.array_equal(est.total, np.full(6, 32 * 2e-4))
-    assert int(est.n_sampled.sum()) == 0
-    # and the planner sees the calibrated zero-cost down-clock floor
-    pa = plan_estimates(est, float(est.total.sum()) * 1.1, cfg)
-    ba = est.to_block_arrays(roofline=cf.roofline_arrays(est.n_records))
-    assert ba.roofline is not None and bool(ba.roofline.has.all())
-    zero_cost = ba.roofline.t_comp / ba.roofline.t_mem
-    assert np.allclose(zero_cost, 1.0 - cf.mem_fraction)
-    assert pa.feasible
-
-
-def test_pipeline_config_rejects_unknown_calibration():
-    with pytest.raises(TypeError, match="calibration"):
-        plan_estimates(stream_estimates(synthetic_cost_chunks(4, 8, seed=0),
-                                        PipelineConfig()),
-                       100.0, PipelineConfig(calibration=object()))

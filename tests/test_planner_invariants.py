@@ -7,7 +7,8 @@ hypothesis is not installed.  The contract (also documented in
   * a plan reported feasible predicts completion inside the deadline,
   * every planned frequency is a state of the governing ladder,
   * DV-DVFS busy energy never exceeds DVO (all-f_max) on the same blocks,
-  * the roofline planner never pays time for memory-bound down-clocks.
+  * the global planner never pays time for roofline blocks' memory-bound
+    down-clocks.
 """
 import numpy as np
 import pytest
@@ -75,7 +76,7 @@ def test_roofline_never_pays_time_for_memory_bound_downclock(
     blocks = [BlockInfo(i, rt.time_at(1.0), roofline=rt)
               for i in range(n_blocks)]
     t_fmax = sum(b.est_time_fmax for b in blocks)
-    plan = plan_dvfs(blocks, t_fmax * 1.0001, planner="roofline",
+    plan = plan_dvfs(blocks, t_fmax * 1.0001, planner="global",
                      error_margin=0.0)
     f_star = rt.zero_cost_freq()
     for b, bp in zip(blocks, plan.blocks):

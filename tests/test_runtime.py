@@ -27,8 +27,8 @@ from _hypothesis_compat import given, settings, st
 from repro.core import BlockInfo, FrequencyLadder
 from repro.core.scheduler import block_time_table, busy_energy_table
 from repro.cluster import (NodeSpec, SlowdownEvent, assign_blocks,
-                           plan_cluster, simulate_cluster,
-                           simulate_cluster_reference)
+                           plan_cluster, plan_cluster_arrays,
+                           simulate_cluster, simulate_cluster_reference)
 from repro.cluster.planner import BlockPlan, ClusterPlan, NodePlan
 from repro.runtime import (ActuationModel, FaultEvent, RuntimeConfig,
                            run_cluster)
@@ -379,24 +379,21 @@ def test_full_feature_run_is_deterministic():
 
 
 def test_pipeline_stream_run_handoff():
-    """Dataset -> plan -> runtime, SoA end to end: the streamed plan feeds
-    the engine directly and executes drift-free against its own estimates
-    (finish == prediction per node, deadline met on a feasible plan)."""
-    from repro.pipeline import (PipelineConfig, stream_estimates, stream_run,
-                                synthetic_cost_chunks)
-    cfg = PipelineConfig()
+    """Dataset -> plan -> runtime, SoA end to end: the streamed estimates'
+    cluster plan feeds the engine directly and executes drift-free against
+    its own estimates (finish == prediction per node, deadline met on a
+    feasible plan)."""
+    from repro.pipeline import stream_estimates, synthetic_cost_chunks
     nodes = _nodes(3)
-    est = stream_estimates(synthetic_cost_chunks(600, 32, seed=1), cfg)
+    est = stream_estimates(synthetic_cost_chunks(600, 32, seed=1))
     deadline = float(est.total.sum()) / (0.8 * len(nodes)) * 1.5
-    rep = stream_run(est, deadline, cfg, nodes=nodes,
-                     assignment="round_robin")
+    ba = est.to_block_arrays()
+    cpa = plan_cluster_arrays(ba, nodes, deadline, assignment="round_robin")
+    rep = run_cluster(cpa, ba, config=RuntimeConfig(log_events=False))
     assert rep.deadline_met
     assert sum(nr.n_blocks for nr in rep.node_reports) == 600
     # truth == estimates: execution realizes the plan's own predictions
-    from repro.cluster import plan_cluster
-    plan = plan_cluster(est.to_block_arrays(), nodes, deadline,
-                        assignment="round_robin")
-    for nr, npa in zip(rep.node_reports, plan.node_plans):
+    for nr, npa in zip(rep.node_reports, cpa.node_plans):
         assert nr.busy_s == pytest.approx(npa.pred_finish_s, rel=1e-12)
 
 

@@ -9,7 +9,7 @@ from repro.models import transformer as T
 from repro.serve import ServeConfig, ServingEngine
 
 
-def _engine(planner="roofline", window=8, mem_bound=True, **sc_kw):
+def _engine(planner="global", window=8, mem_bound=True, **sc_kw):
     cfg = smoke_config("olmo-1b")
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     rt = RooflineTimeModel.from_counts(
@@ -37,7 +37,8 @@ def test_generate_shapes_and_determinism():
 
 
 def test_memory_bound_decode_gets_free_downclock():
-    """Roofline planner on a memory-bound decode: energy drops, clocks < 1."""
+    """Global planner on a memory-bound decode roofline: energy drops,
+    clocks < 1."""
     eng, prompts = _engine(mem_bound=True)
     out = eng.generate(prompts, n_tokens=32)
     assert out["energy"]["busy_j"] < out["energy_dvo"]["busy_j"]
